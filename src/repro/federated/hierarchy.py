@@ -271,7 +271,11 @@ class ShardedPartialFolder:
     ``jax.lax.psum`` over the pod axis produces the replicated total.
     This is the same mesh plumbing `pod_fedavg` uses for replica stacks;
     on a single-device host the mesh degenerates to one shard and the
-    math is unchanged."""
+    math is unchanged.
+
+    :meth:`place` builds the stack shard by shard: each accumulator is
+    copied straight to the device that owns its row, so no device ever
+    holds the whole ``(R, L_pad)`` stack."""
 
     def __init__(self, mesh: Optional[Any] = None) -> None:
         if mesh is None:
@@ -280,33 +284,64 @@ class ShardedPartialFolder:
         self.pod_size = int(mesh.shape["pod"])
         self._fn: Optional[Callable[..., Any]] = None
 
-    def _reduce_fn(self) -> Callable[..., Any]:
+    def reduce_fn(self) -> Callable[..., Any]:
+        """The jitted ``(R_pad, L_pad) -> (L_pad,)`` shard_map + psum."""
         if self._fn is None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             def local_sum(stack: Any) -> Any:
                 return jax.lax.psum(jnp.sum(stack, axis=0), "pod")
 
             self._fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     local_sum, mesh=self.mesh,
                     in_specs=P("pod", None), out_specs=P(),
                 )
             )
         return self._fn
 
-    def reduce(self, accs: Sequence[Any]) -> Any:
-        """Sum R accumulators into one ``(L_pad,)`` fp32 vector."""
+    def place(self, accs: Sequence[Any]) -> Any:
+        """Stack R accumulators as an ``(R_pad, L_pad)`` array sharded
+        over the pod axis, zero rows padding R to a multiple of the pod
+        size.  Each device receives only its own rows."""
         if not accs:
             raise ValueError("nothing to reduce")
-        rows = jnp.stack([jnp.asarray(a, jnp.float32) for a in accs])
-        pad = (-rows.shape[0]) % self.pod_size
-        if pad:
-            rows = jnp.concatenate(
-                [rows, jnp.zeros((pad, rows.shape[1]), jnp.float32)]
-            )
-        return self._reduce_fn()(rows)
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        rows = list(accs)
+        length = int(np.shape(rows[0])[0])
+        n_rows = len(rows) + (-len(rows)) % self.pod_size
+        sharding = NamedSharding(self.mesh, P("pod", None))
+        shards = []
+        for device, index in sharding.addressable_devices_indices_map(
+            (n_rows, length)
+        ).items():
+            lo, hi, _ = index[0].indices(n_rows)
+            mine = [
+                jax.device_put(jnp.asarray(rows[r], jnp.float32), device)
+                if r < len(rows)
+                else jnp.zeros((length,), jnp.float32, device=device)
+                for r in range(lo, hi)
+            ]
+            shards.append(jnp.stack(mine))
+        return jax.make_array_from_single_device_arrays(
+            (n_rows, length), sharding, shards
+        )
+
+    def reduce(self, accs: Sequence[Any]) -> Any:
+        """Sum R accumulators into one ``(L_pad,)`` fp32 vector.
+
+        The psum leaves the total on every device of the mesh; the
+        returned array is the copy on the device that held the first
+        accumulator (where the parent aggregator folds it)."""
+        total = self.reduce_fn()(self.place(accs))
+        home = getattr(accs[0], "devices", None)
+        devices = home() if callable(home) else set()
+        for shard in total.addressable_shards:
+            if shard.device in devices:
+                return shard.data
+        return total.addressable_shards[0].data
 
 
 # ---------------------------------------------------------------------------

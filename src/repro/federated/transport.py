@@ -602,6 +602,71 @@ def run_client_worker(
         hook = getattr(client, "mangle_payload", None)
         return bytes(hook(body)) if callable(hook) else body
 
+    def _run_job(header: Mapping[str, Any], payload: bytes) -> None:
+        # One s_msg_train / s_msg_aggreg job.  Its device buffers (the
+        # received weights, the trained result) are locals, released when
+        # the job returns rather than held until the next one rebinds them.
+        kind = header.get("kind")
+        round_idx = int(header.get("round_idx", 0))
+        on_round = getattr(client, "on_round", None)
+        if callable(on_round):
+            on_round(round_idx, "train" if kind == MSG_S_TRAIN else "eval")
+        params = deserialize_pytree(payload, template_params)
+        if kind == MSG_S_TRAIN:
+            result = client.train(params)
+            header_out = {
+                "kind": MSG_C_TRAIN,
+                "round_idx": round_idx,
+                "client_id": str(client.client_id),
+                "n_samples": int(result.n_samples),
+                "train_time_s": float(result.train_time_s),
+            }
+            if struct_encoder is not None:
+                from .agg_engine import plan_for
+                from .compression import serialize_structured
+
+                supdate = struct_encoder.encode(
+                    params, result.params, base_round=round_idx
+                )
+                header_out["structured"] = 1
+                # Dense equivalent = the FULL model's fp32 bytes:
+                # the savings being reported is "groups instead
+                # of the whole pytree", codec included.
+                header_out["dense_bytes"] = int(
+                    plan_for(params).total_elems * 4
+                )
+                header_out["group_bytes"] = {
+                    str(k): int(v)
+                    for k, v in supdate.group_wire_bytes().items()
+                }
+                header_out["group_dense"] = {
+                    str(k): int(v)
+                    for k, v in supdate.group_dense_bytes().items()
+                }
+                body = serialize_structured(supdate)
+            elif compressor is not None:
+                from .compression import serialize_update
+
+                update = compressor.encode(params, result.params)
+                header_out["codec"] = update.codec
+                header_out["dense_bytes"] = int(update.dense_bytes)
+                body = serialize_update(update)
+            else:
+                body = serialize_pytree(result.params)
+            _send(header_out, _mangle(body))
+        else:
+            ev = client.evaluate(params)
+            _send(
+                {
+                    "kind": MSG_C_TEST,
+                    "round_idx": round_idx,
+                    "client_id": str(client.client_id),
+                    "n_samples": int(ev.n_samples),
+                    "eval_time_s": float(ev.eval_time_s),
+                },
+                _mangle(serialize_metrics(ev.metrics)),
+            )
+
     def _compute_loop() -> None:
         # A raising client IS the crash model: shut the socket down so
         # the server sees EOF, and exit quietly — the §4.3 recovery
@@ -611,69 +676,7 @@ def run_client_worker(
                 job = jobs.get()
                 if job is None:
                     return
-                header, payload = job
-                kind = header.get("kind")
-                round_idx = int(header.get("round_idx", 0))
-                on_round = getattr(client, "on_round", None)
-                if callable(on_round):
-                    on_round(
-                        round_idx, "train" if kind == MSG_S_TRAIN else "eval"
-                    )
-                params = deserialize_pytree(payload, template_params)
-                if kind == MSG_S_TRAIN:
-                    result = client.train(params)
-                    header_out = {
-                        "kind": MSG_C_TRAIN,
-                        "round_idx": round_idx,
-                        "client_id": str(client.client_id),
-                        "n_samples": int(result.n_samples),
-                        "train_time_s": float(result.train_time_s),
-                    }
-                    if struct_encoder is not None:
-                        from .agg_engine import plan_for
-                        from .compression import serialize_structured
-
-                        supdate = struct_encoder.encode(
-                            params, result.params, base_round=round_idx
-                        )
-                        header_out["structured"] = 1
-                        # Dense equivalent = the FULL model's fp32 bytes:
-                        # the savings being reported is "groups instead
-                        # of the whole pytree", codec included.
-                        header_out["dense_bytes"] = int(
-                            plan_for(params).total_elems * 4
-                        )
-                        header_out["group_bytes"] = {
-                            str(k): int(v)
-                            for k, v in supdate.group_wire_bytes().items()
-                        }
-                        header_out["group_dense"] = {
-                            str(k): int(v)
-                            for k, v in supdate.group_dense_bytes().items()
-                        }
-                        body = serialize_structured(supdate)
-                    elif compressor is not None:
-                        from .compression import serialize_update
-
-                        update = compressor.encode(params, result.params)
-                        header_out["codec"] = update.codec
-                        header_out["dense_bytes"] = int(update.dense_bytes)
-                        body = serialize_update(update)
-                    else:
-                        body = serialize_pytree(result.params)
-                    _send(header_out, _mangle(body))
-                else:
-                    ev = client.evaluate(params)
-                    _send(
-                        {
-                            "kind": MSG_C_TEST,
-                            "round_idx": round_idx,
-                            "client_id": str(client.client_id),
-                            "n_samples": int(ev.n_samples),
-                            "eval_time_s": float(ev.eval_time_s),
-                        },
-                        _mangle(serialize_metrics(ev.metrics)),
-                    )
+                _run_job(*job)
         except Exception:  # noqa: BLE001 — crash-to-EOF is the §4.3 contract
             try:
                 sock.shutdown(socket.SHUT_RDWR)
@@ -859,7 +862,13 @@ class ProcessWorkerPool:
     spawn/import latency (seconds per worker; the slow-tier test covers
     it, CI smoke runs on threads).  Like :class:`ThreadWorkerPool`, a
     §4.4 cross-host ``restart(..., host=...)`` is tracked per silo (the
-    replacement process *is* the replacement VM in this model)."""
+    replacement process *is* the replacement VM in this model).
+
+    CPU hosts only: an accelerator belongs to one process, and the
+    driver's own process already holds it (it folds on the device), so
+    a spawned silo that imports JAX would fail or hang reaching for it.
+    Off the CPU the pool refuses to start; run the silos as threads
+    (``transport(kind="thread")``) in the process that holds the chip."""
 
     def __init__(
         self,
@@ -869,6 +878,14 @@ class ProcessWorkerPool:
         compression: Optional[Any] = None,
         schema: Optional[Any] = None,
     ) -> None:
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"ProcessWorkerPool needs a CPU backend, but this process "
+                f"runs on {backend!r}: the parent holds the device, and a "
+                "spawned silo cannot share it.  Use transport(kind="
+                '"thread") to run the silos in this process.'
+            )
         self._factories: Dict[str, Callable[[], Any]] = dict(client_factories)
         # Numpy-ify so the template pickles without device buffers.
         self._template_np = jax.tree.map(np.asarray, template_params)

@@ -9,7 +9,7 @@
 Dispatch hierarchy: ops.py is the entry point — it routes each call to
 the Pallas implementation or the pure-jnp oracle in ref.py, and resolves
 interpret mode by backend detection (`jax.default_backend() != "tpu"`),
-overridable via `REPRO_KERNEL_INTERPRET` or an explicit ``interpret=``.
+overridable only by an explicit ``interpret=`` argument.
 The federated aggregation engine (`repro.federated.agg_engine`) sits one
 layer above: it feeds `fedavg_reduce` a flatten-once (N, L) client
 buffer on TPU (donated, so HBM is reused) and a fused jnp contraction

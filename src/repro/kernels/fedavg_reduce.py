@@ -67,14 +67,47 @@ def fedavg_reduce(
     return out[0, :L]
 
 
-def _dequant_fold_kernel(w_ref, s_ref, a_ref, x_ref, o_ref):
-    """w: (1, 1) fold weight; s: (1, 1) per-block scale; a/x/o: (1, BLOCK).
+# Quantization blocks folded per grid step.  Each block is one row of the
+# (nb, BLOCK) view, so a step moves an (ROWS, BLOCK) tile: 32 rows is the
+# int8 sublane tile (32, 128) and a multiple of fp16's (16, 128), and the
+# double-buffered working set (int8 data + fp32 acc in + fp32 out, about
+# 2.3 MiB per buffer) stays well inside scoped VMEM.
+ROWS = 32
 
-    One fused pass: dequantize the tile (``x * scale``), weight it, and
-    add it onto the fp32 accumulator tile — the quantized bytes are read
+
+def _half_bits_to_f32(h: jnp.ndarray) -> jnp.ndarray:
+    """Exact IEEE fp16 -> fp32 decode of uint16 bit patterns.
+
+    Mosaic cannot load float16 vectors, so fp16 updates enter the kernel
+    as their uint16 bits and are widened here with integer ops: normals,
+    infinities and NaNs by re-biasing the exponent, subnormals (which
+    would flush to zero as fp32 denormals on the VPU) as
+    ``mantissa * 2**-24``.  Bit-for-bit equal to ``astype(float32)``."""
+    h = h.astype(jnp.int32)
+    sign = (h >> 15) << 31
+    exp = (h >> 10) & 0x1F
+    man = h & 0x3FF
+    exp32 = jnp.where(exp == 0x1F, 0xFF, exp + (127 - 15))
+    normal = jax.lax.bitcast_convert_type(
+        sign | (exp32 << 23) | (man << 13), jnp.float32
+    )
+    sub = man.astype(jnp.float32) * (2.0 ** -24)
+    sub = jnp.where(sign != 0, -sub, sub)
+    return jnp.where(exp == 0, sub, normal)
+
+
+def _dequant_fold_kernel(s_ref, a_ref, x_ref, o_ref):
+    """s: (R, 1) weighted per-block scales; a/x/o: (R, BLOCK).
+
+    One fused pass: dequantize each row (``x * scale``), weight it, and
+    add it onto the fp32 accumulator rows — the quantized bytes are read
     once and no dense fp32 copy of the update is ever materialized."""
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] = a_ref[...] + (w_ref[0, 0] * s_ref[0, 0]) * x
+    raw = x_ref[...]
+    if raw.dtype == jnp.uint16:  # fp16 payload, passed as its bits
+        x = _half_bits_to_f32(raw)
+    else:
+        x = raw.astype(jnp.float32)
+    o_ref[...] = a_ref[...] + s_ref[...] * x
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
@@ -87,11 +120,15 @@ def dequant_fold(
 ) -> jnp.ndarray:
     """Fused dequantize-and-fold: ``acc + weight * (data * scales)``.
 
-    Quantization blocks are exactly the kernel's grid tiles (one wire
-    scale per (1, BLOCK) tile), so each int8/fp16 tile is dequantized in
-    VREGs and accumulated in a single HBM pass.  The accumulator is
-    donated and aliased to the output (updated in place, O(L) memory for
-    the whole round).  fp16 updates reuse the same kernel with unit
+    Quantization blocks are exactly the rows of the ``(Lp // BLOCK,
+    BLOCK)`` view (one wire scale per row); the grid walks it ``ROWS``
+    rows at a time, with the weighted scales as an ``(ROWS, 1)`` column
+    block, so each int8/fp16 tile is dequantized in VREGs and
+    accumulated in a single HBM pass.  A row count that is not a
+    multiple of ``ROWS`` leaves a partial last block, whose
+    out-of-range rows are never written back.  The accumulator is
+    donated and aliased to the output (updated in place, O(L) memory
+    for the whole round).  fp16 updates reuse the same kernel with unit
     scales.  Like ``fedavg_reduce``: compiled Mosaic on TPU, interpreter
     elsewhere.
     """
@@ -101,23 +138,27 @@ def dequant_fold(
     if Lp % BLOCK:
         raise ValueError(f"accumulator length {Lp} not a multiple of BLOCK={BLOCK}")
     nb = Lp // BLOCK
+    # Fewer rows than one tile: the block spans the whole (nb, BLOCK)
+    # array, which Mosaic accepts at any row count.
+    rows = ROWS if nb > ROWS else nb
+    if data.dtype == jnp.float16:
+        data = jax.lax.bitcast_convert_type(data, jnp.uint16)
     a2 = acc.reshape(nb, BLOCK)
     x2 = data.reshape(nb, BLOCK)
-    s2 = scales.astype(jnp.float32).reshape(nb, 1)
-    w2 = jnp.asarray(weight, jnp.float32).reshape(1, 1)
+    w = jnp.asarray(weight, jnp.float32)
+    ws = (w * scales.astype(jnp.float32)).reshape(nb, 1)
 
     out = pl.pallas_call(
         _dequant_fold_kernel,
         out_shape=jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
-        grid=(nb,),
+        grid=(pl.cdiv(nb, rows),),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),       # weight: replicated
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),       # this tile's scale
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),   # accumulator tile
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),   # quantized tile
+            pl.BlockSpec((rows, 1), lambda i: (i, 0)),       # weighted scales
+            pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),   # accumulator rows
+            pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),   # quantized rows
         ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        input_output_aliases={2: 0},  # accumulator updated in place
+        out_specs=pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),
+        input_output_aliases={1: 0},  # accumulator updated in place
         interpret=interpret,
-    )(w2, s2, a2, x2)
+    )(ws, a2, x2)
     return out.reshape(Lp)

@@ -4,12 +4,12 @@ Pallas implementation or the pure-jnp oracle.
 Interpret mode is backend-detected: on a TPU runtime the same
 `pl.pallas_call` lowers to Mosaic (`interpret=False`); everywhere else
 (CPU/GPU containers) the kernels execute via the Pallas interpreter.
-`REPRO_KERNEL_INTERPRET=0|1` (or an explicit ``interpret=`` argument)
-overrides the detection — tests use the explicit override.
+Only an explicit ``interpret=`` argument overrides the detection (tests
+use it); nothing in the environment can switch a TPU run to the
+interpreter.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -22,9 +22,6 @@ from .ssd_scan import ssd_chunk_scan as _ssd_pallas
 
 
 def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env != "0"
     return jax.default_backend() != "tpu"
 
 

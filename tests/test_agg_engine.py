@@ -380,12 +380,14 @@ def test_server_round_uses_fused_engine():
 # ---------------------------------------------------------------------------
 
 def test_interpret_default_backend_detection(monkeypatch):
-    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
     assert ops._interpret_default() == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
+    # The backend alone decides: compiled Mosaic on a TPU runtime, the
+    # Pallas interpreter everywhere else.
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
     assert ops._interpret_default() is False
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
-    assert ops._interpret_default() is True
+    for backend in ("cpu", "gpu"):
+        monkeypatch.setattr(ops.jax, "default_backend", lambda b=backend: b)
+        assert ops._interpret_default() is True
 
 
 def test_measured_aggreg_fn_feeds_cost_model():

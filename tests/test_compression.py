@@ -219,6 +219,46 @@ def test_dequant_fold_kernel_matches_reference(codec):
     np.testing.assert_allclose(np.asarray(out)[n:], ref[n:], atol=1e-6)
 
 
+@pytest.mark.parametrize("codec", ["int8", "fp16"])
+def test_dequant_fold_partial_last_row_block_matches_jnp_fold(codec):
+    """The kernel walks ROWS quantization blocks per grid step; a block
+    count that is not a multiple of ROWS leaves a partial last step,
+    which must fold exactly like the jitted jnp fold — and no row past
+    the accumulator may be written."""
+    from repro.federated.agg_engine import _flat_dequant_fold_jnp
+    from repro.kernels.fedavg_reduce import ROWS
+
+    nb = 2 * ROWS + 5
+    lp = nb * BLOCK
+    cu = compress(_rand_vec(lp - 77, seed=8), CompressionSpec(codec))
+    data = np.zeros(lp, dtype=np.asarray(cu.data).dtype)
+    data[: cu.total_elems] = cu.data
+    scales = (
+        np.asarray(cu.scales, np.float32)
+        if cu.scales is not None else np.ones(nb, np.float32)
+    )
+    acc0 = _rand_vec(lp, seed=9)
+    args = (jnp.asarray(data), jnp.asarray(scales), jnp.float32(0.75))
+    out = dequant_fold(jnp.asarray(acc0), *args, interpret=True)
+    want = _flat_dequant_fold_jnp(jnp.asarray(acc0), *args)
+    assert out.shape == (lp,)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def test_dequant_fold_decodes_every_fp16_bit_pattern():
+    """fp16 payloads enter the kernel as uint16 bits (Mosaic cannot load
+    float16 vectors); the in-kernel decode must equal astype(float32) on
+    all 65536 patterns — subnormals, infinities and NaNs included."""
+    from repro.kernels.fedavg_reduce import _half_bits_to_f32
+
+    bits = np.arange(1 << 16, dtype=np.uint16)
+    want = bits.view(np.float16).astype(np.float32)
+    got = np.asarray(_half_bits_to_f32(jnp.asarray(bits)))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32), want[~nan].view(np.uint32))
+
+
 def test_dequant_fold_rejects_unpadded_acc():
     with pytest.raises(ValueError, match="BLOCK"):
         dequant_fold(

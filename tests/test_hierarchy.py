@@ -205,6 +205,44 @@ def test_sharded_folder_pads_to_pod_multiple():
     )
 
 
+_FOUR_DEVICE_FOLD = """
+import jax, numpy as np
+from repro.federated.hierarchy import ShardedPartialFolder
+
+folder = ShardedPartialFolder()
+accs = [jax.device_put(np.full(8, float(i + 1), np.float32), jax.devices()[0])
+        for i in range(6)]
+stack = folder.place(accs)
+rows = sorted((s.device.id, s.data.shape, tuple(np.asarray(s.data)[:, 0]))
+              for s in stack.addressable_shards)
+assert rows == [(0, (2, 8), (1.0, 2.0)), (1, (2, 8), (3.0, 4.0)),
+                (2, (2, 8), (5.0, 6.0)), (3, (2, 8), (0.0, 0.0))], rows
+out = folder.reduce(accs)
+assert out.devices() == {jax.devices()[0]}, out.devices()
+np.testing.assert_array_equal(np.asarray(out), np.full(8, 21.0, np.float32))
+"""
+
+
+def test_sharded_folder_places_each_row_on_its_own_device():
+    """On a 4-device mesh each device receives only its own rows (the
+    stack is never gathered on one device), zero rows pad the tail, and
+    the psum total comes back on the accumulators' home device."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.abspath(src),
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", _FOUR_DEVICE_FOLD],
+        capture_output=True, text=True, env=env, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 # ---------------------------------------------------------------------------
 # partial-sum export/fold contract
 # ---------------------------------------------------------------------------

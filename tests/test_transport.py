@@ -429,6 +429,20 @@ def test_thread_pool_rejects_duplicate_ids():
         ThreadWorkerPool(clients + clients, init_params())
 
 
+@pytest.mark.parametrize("backend", ["tpu", "gpu"])
+def test_process_pool_refuses_an_accelerator_backend(monkeypatch, backend):
+    """A spawned silo cannot reach a chip the parent already holds: off
+    the CPU the process pool refuses to start and points at threads."""
+    from repro.federated import transport
+
+    monkeypatch.setattr(transport.jax, "default_backend", lambda: backend)
+    factories = {"c0": lambda: None}
+    with pytest.raises(RuntimeError, match=r'transport\(kind="thread"\)'):
+        transport.ProcessWorkerPool(factories, init_params())
+    with pytest.raises(RuntimeError, match=backend):
+        Experiment().transport(kind="process").serve(factories, init_params())
+
+
 def test_non_consecutive_timeouts_do_not_escalate():
     """An on-time reply clears the timeout-miss streak even without a
     RoundDeadline configured — two timeouts with an on-time round in
