@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import time
 from collections import OrderedDict
 from typing import (
     Any,
@@ -66,6 +67,8 @@ from typing import (
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import spans
 
 
 # ---------------------------------------------------------------------------
@@ -1056,12 +1059,8 @@ def _fold_compressed_into(
     one.  ``acc`` is donated by the underlying jitted folds; callers
     must rebind to the return value."""
     if update.codec == "topk":
-        return _flat_scatter_fold(
-            acc,
-            jnp.asarray(np.asarray(update.indices)),
-            jnp.asarray(np.asarray(update.data)),
-            jnp.float32(w),
-        )
+        idx, vals = _to_device(np.asarray(update.indices), np.asarray(update.data))
+        return _flat_scatter_fold(acc, idx, vals, jnp.float32(w))
     if update.codec in ("int8", "fp16"):
         from repro.federated.compression import QBLOCK
         nb = padded_len // QBLOCK
@@ -1075,16 +1074,24 @@ def _fold_compressed_into(
                 )
         else:
             scales = np.ones(nb, np.float32)
+        data_d, scales_d = _to_device(data, scales)
         if use_pallas:
             from repro.kernels.fedavg_reduce import dequant_fold
             return dequant_fold(
-                acc, jnp.asarray(data), jnp.asarray(scales),
-                jnp.float32(w), interpret=interpret,
+                acc, data_d, scales_d, jnp.float32(w), interpret=interpret,
             )
-        return _flat_dequant_fold_jnp(
-            acc, jnp.asarray(data), jnp.asarray(scales), jnp.float32(w)
-        )
+        return _flat_dequant_fold_jnp(acc, data_d, scales_d, jnp.float32(w))
     raise ValueError(f"unknown compressed codec {update.codec!r}")
+
+
+def _to_device(*arrays: np.ndarray) -> List[Any]:
+    """A compressed payload's host arrays onto the device.  Counters
+    ``h2d_s`` (host side of the copies) and ``h2d_bytes``."""
+    t0 = time.perf_counter()
+    out = [jnp.asarray(a) for a in arrays]
+    spans.add("h2d_s", time.perf_counter() - t0)
+    spans.add("h2d_bytes", sum(a.nbytes for a in arrays))
+    return out
 
 
 def _leaf_nbytes(leaf: Any) -> int:

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
+
 
 @dataclasses.dataclass
 class ClientResult:
@@ -99,33 +101,45 @@ class FLClient:
 
     # -- training phase ------------------------------------------------------
     def train(self, global_params: Any) -> ClientResult:
-        t0 = time.monotonic()
-        params = global_params
-        # Fresh optimizer state per round (clients are stateless across
-        # rounds w.r.t. the optimizer; only weights flow through the server).
-        opt_state = self.optimizer.init(params)
-        # n_samples is the silo's per-epoch example count — the FedAvg
-        # weight (§3).  Count one epoch's pass exactly rather than
-        # dividing the multi-epoch total: with ragged last batches the
-        # per-epoch counts are equal, but integer-dividing the sum would
-        # under-count whenever an epoch's total isn't a multiple of
-        # local_epochs, skewing weights across silos with different
-        # batch remainders.
-        n_first_epoch = 0
-        last_loss = None
-        for epoch in range(self.local_epochs):
-            for raw in self.silo.batches(self.batch_size, split="train"):
-                batch = self.batch_fn(raw)
-                params, opt_state, last_loss = self._train_step(params, opt_state, batch)
-                if epoch == 0:
-                    n_first_epoch += _batch_count(raw)
-        jax.block_until_ready(last_loss)
-        return ClientResult(
-            client_id=self.client_id,
-            params=params,
-            n_samples=n_first_epoch,
-            train_time_s=time.monotonic() - t0,
-        )
+        """Local training: span ``fl.train``, with counters ``step_calls``
+        and ``step_dispatch_s`` (host time inside the step calls) and the
+        closing ``block_until_ready`` as span ``fl.drain``."""
+        with spans.span("fl.train"):
+            t0 = time.monotonic()
+            params = global_params
+            # Fresh optimizer state per round (clients are stateless across
+            # rounds w.r.t. the optimizer; only weights flow through the server).
+            opt_state = self.optimizer.init(params)
+            # n_samples is the silo's per-epoch example count — the FedAvg
+            # weight (§3).  Count one epoch's pass exactly rather than
+            # dividing the multi-epoch total: with ragged last batches the
+            # per-epoch counts are equal, but integer-dividing the sum would
+            # under-count whenever an epoch's total isn't a multiple of
+            # local_epochs, skewing weights across silos with different
+            # batch remainders.
+            n_first_epoch = 0
+            last_loss = None
+            calls = 0
+            dispatch_s = 0.0
+            for epoch in range(self.local_epochs):
+                for raw in self.silo.batches(self.batch_size, split="train"):
+                    batch = self.batch_fn(raw)
+                    t_call = time.perf_counter()
+                    params, opt_state, last_loss = self._train_step(params, opt_state, batch)
+                    dispatch_s += time.perf_counter() - t_call
+                    calls += 1
+                    if epoch == 0:
+                        n_first_epoch += _batch_count(raw)
+            spans.add("step_calls", calls)
+            spans.add("step_dispatch_s", dispatch_s)
+            with spans.span("fl.drain"):
+                jax.block_until_ready(last_loss)
+            return ClientResult(
+                client_id=self.client_id,
+                params=params,
+                n_samples=n_first_epoch,
+                train_time_s=time.monotonic() - t0,
+            )
 
     def encode_update(self, global_params: Any, local_params: Any) -> Any:
         """Compress this round's update with the client-owned
@@ -139,34 +153,36 @@ class FLClient:
 
     # -- evaluation phase -----------------------------------------------------
     def evaluate(self, aggregated_params: Any) -> EvalResult:
-        t0 = time.monotonic()
-        sums: Dict[str, float] = {}
-        n = 0
-        for raw in self.silo.batches(self.batch_size, split="test"):
-            batch = self.batch_fn(raw)
-            if self._jit_eval is not None:
-                out = self._jit_eval(aggregated_params, batch)
-            else:
-                out = {"loss_sum": self.loss_fn(aggregated_params, batch) * _batch_count(raw)}
-            for k, v in out.items():
-                sums[k] = sums.get(k, 0.0) + float(v)
-            n += _batch_count(raw)
-        # Average only the keys that declare themselves example-weighted
-        # sums via a "_sum" suffix, stripping exactly that suffix.  A
-        # blanket k.replace("_sum", "")/n would mangle keys merely
-        # *containing* the substring (loss_summary -> losmary) and turn
-        # already-normalized metrics into nonsense rates.
-        metrics = {
-            (k[: -len("_sum")] if k.endswith("_sum") else k):
-                (v / max(n, 1) if k.endswith("_sum") else v)
-            for k, v in sums.items()
-        }
-        return EvalResult(
-            client_id=self.client_id,
-            metrics=metrics,
-            n_samples=n,
-            eval_time_s=time.monotonic() - t0,
-        )
+        """The evaluation phase on the silo's test split (span ``fl.evaluate``)."""
+        with spans.span("fl.evaluate"):
+            t0 = time.monotonic()
+            sums: Dict[str, float] = {}
+            n = 0
+            for raw in self.silo.batches(self.batch_size, split="test"):
+                batch = self.batch_fn(raw)
+                if self._jit_eval is not None:
+                    out = self._jit_eval(aggregated_params, batch)
+                else:
+                    out = {"loss_sum": self.loss_fn(aggregated_params, batch) * _batch_count(raw)}
+                for k, v in out.items():
+                    sums[k] = sums.get(k, 0.0) + float(v)
+                n += _batch_count(raw)
+            # Average only the keys that declare themselves example-weighted
+            # sums via a "_sum" suffix, stripping exactly that suffix.  A
+            # blanket k.replace("_sum", "")/n would mangle keys merely
+            # *containing* the substring (loss_summary -> losmary) and turn
+            # already-normalized metrics into nonsense rates.
+            metrics = {
+                (k[: -len("_sum")] if k.endswith("_sum") else k):
+                    (v / max(n, 1) if k.endswith("_sum") else v)
+                for k, v in sums.items()
+            }
+            return EvalResult(
+                client_id=self.client_id,
+                metrics=metrics,
+                n_samples=n,
+                eval_time_s=time.monotonic() - t0,
+            )
 
 
 def _batch_count(raw) -> int:

@@ -31,11 +31,13 @@ the fused Pallas dequantize-and-fold kernel (``dequant_fold``).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Tuple, Union
 
 import msgpack
 import numpy as np
 
+from repro import spans
 from repro.checkpoint.serializer import DeserializationError
 
 # One quantization block per Pallas grid tile of the fused
@@ -275,9 +277,14 @@ def _update_obj(update: CompressedUpdate) -> Dict[str, Any]:
 
 
 def serialize_update(update: CompressedUpdate) -> bytes:
-    """msgpack wire form of a compressed update (a c_msg_train payload)."""
-    packed = msgpack.packb(_update_obj(update), use_bin_type=True)
-    assert isinstance(packed, bytes)
+    """msgpack wire form of a compressed update (a c_msg_train payload).
+    Counter ``pack_s`` (``tobytes`` and ``packb``)."""
+    with spans.span("fl.serialize") as sp:
+        t0 = time.perf_counter()
+        packed = msgpack.packb(_update_obj(update), use_bin_type=True)
+        spans.add("pack_s", time.perf_counter() - t0)
+        assert isinstance(packed, bytes)
+        sp.nbytes = len(packed)
     return packed
 
 
@@ -288,16 +295,22 @@ def deserialize_update(payload: bytes) -> CompressedUpdate:
     any malformed, truncated, or internally inconsistent frame — the same
     typed error the dense path raises, so the transport's corrupt-frame
     re-request recovery (§4.3) applies unchanged to compressed frames.
+    Counter ``unpack_s`` (``unpackb`` and ``frombuffer``); the payload
+    stays on the host until the fold moves it.
     """
-    try:
-        obj = msgpack.unpackb(payload, raw=False)
-    except Exception as exc:
-        raise DeserializationError(
-            f"malformed compressed update frame: {exc}"
-        ) from exc
-    if not isinstance(obj, dict):
-        raise DeserializationError("compressed update frame is not a map")
-    return _decode_update_obj(obj)
+    with spans.span("fl.deserialize", nbytes=len(payload)):
+        t0 = time.perf_counter()
+        try:
+            obj = msgpack.unpackb(payload, raw=False)
+        except Exception as exc:
+            raise DeserializationError(
+                f"malformed compressed update frame: {exc}"
+            ) from exc
+        if not isinstance(obj, dict):
+            raise DeserializationError("compressed update frame is not a map")
+        update = _decode_update_obj(obj)
+        spans.add("unpack_s", time.perf_counter() - t0)
+        return update
 
 
 def _decode_update_obj(obj: Dict[str, Any]) -> CompressedUpdate:
@@ -582,18 +595,24 @@ class ClientCompressor:
 
         ``base_round`` tags the update with the round those globals
         belong to, so the aggregator can refuse to fold it against any
-        other base (see :class:`CompressedUpdate`)."""
+        other base (see :class:`CompressedUpdate`).  Counters ``d2h_s``/
+        ``d2h_bytes``: the two flattened weight vectors' copy to the host."""
         from repro.federated.agg_engine import plan_for
 
-        plan = plan_for(global_params)
-        g = np.asarray(plan.flatten(global_params), dtype=np.float32)
-        p = np.asarray(plan.flatten(local_params), dtype=np.float32)
-        delta = p - g
-        if self.spec.error_feedback and self._residual is not None:
-            delta = delta + self._residual
-        update = compress(delta, self.spec, base_round=base_round)
-        if self.spec.error_feedback:
-            self._residual = delta - decompress(update)
+        with spans.span("fl.encode") as sp:
+            plan = plan_for(global_params)
+            t0 = time.perf_counter()
+            g = np.asarray(plan.flatten(global_params), dtype=np.float32)
+            p = np.asarray(plan.flatten(local_params), dtype=np.float32)
+            spans.add("d2h_s", time.perf_counter() - t0)
+            spans.add("d2h_bytes", g.nbytes + p.nbytes)
+            delta = p - g
+            if self.spec.error_feedback and self._residual is not None:
+                delta = delta + self._residual
+            update = compress(delta, self.spec, base_round=base_round)
+            if self.spec.error_feedback:
+                self._residual = delta - decompress(update)
+            sp.nbytes = p.nbytes
         return update
 
     def reset(self) -> None:

@@ -36,6 +36,7 @@ from repro.core.events import (
     RecoveryCompleted,
     RoundDispatched,
 )
+from repro.spans import Span
 from .agg_engine import AggregationEngine
 from .aggregation import aggregate_metrics
 from .client import ClientResult, EvalResult, FLClient
@@ -69,6 +70,11 @@ class RoundRecord:
     deadline_s: Optional[float] = None
     carried_over: List[str] = dataclasses.field(default_factory=list)
     carried_in: List[str] = dataclasses.field(default_factory=list)
+    # Live rounds only (LiveRoundDriver): the span records of the driver
+    # and of every silo job whose reply was taken (repro.spans.Span), and
+    # the counters summed over all of them.  Empty for in-process drivers.
+    spans: List[Span] = dataclasses.field(default_factory=list)
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass

@@ -84,6 +84,7 @@ import jax
 import msgpack
 import numpy as np
 
+from repro import spans
 from repro.checkpoint import resolve_freshest
 from repro.checkpoint.serializer import (
     DeserializationError,
@@ -185,17 +186,21 @@ def _recv_exact(sock: socket.socket, n: int) -> Optional[bytes]:
 
 
 def recv_frame(sock: socket.socket) -> Optional[Tuple[Dict[str, Any], bytes]]:
-    """Blocking read of one frame; None on clean EOF (peer closed)."""
+    """Blocking read of one frame; None on clean EOF (peer closed).  The
+    read after the prefix arrives is span ``fl.recv`` (counter
+    ``recv_bytes``)."""
     prefix = _recv_exact(sock, _PREFIX.size)
     if prefix is None:
         return None
     head_len, payload_len = _PREFIX.unpack(prefix)
-    head = _recv_exact(sock, head_len) if head_len else b""
-    if head is None:
-        raise ConnectionError("connection closed mid-frame")
-    payload = _recv_exact(sock, payload_len) if payload_len else b""
-    if payload is None:
-        raise ConnectionError("connection closed mid-frame")
+    with spans.span("fl.recv", nbytes=_PREFIX.size + head_len + payload_len) as sp:
+        head = _recv_exact(sock, head_len) if head_len else b""
+        if head is None:
+            raise ConnectionError("connection closed mid-frame")
+        payload = _recv_exact(sock, payload_len) if payload_len else b""
+        if payload is None:
+            raise ConnectionError("connection closed mid-frame")
+    spans.add("recv_bytes", sp.nbytes)
     return _unpack_header(head), payload
 
 
@@ -210,15 +215,13 @@ class TransportEvent:
     ``kind``: ``"message"`` (a complete frame from an identified client),
     ``"joined"`` (a worker's hello was accepted — first connect or a
     §4.3 restart rejoin), or ``"disconnect"`` (EOF/reset: the silo
-    crashed or shut down).  ``wire_bytes`` counts the frame's full
-    on-the-wire size (prefix + header + payload) for message events.
+    crashed or shut down).
     """
 
     kind: str
     client_id: str
     header: Mapping[str, Any] = dataclasses.field(default_factory=dict)
     payload: bytes = b""
-    wire_bytes: int = 0
 
 
 class _ConnState:
@@ -229,8 +232,8 @@ class _ConnState:
         self.buf = bytearray()
         self.client_id: Optional[str] = None
 
-    def parse_frames(self) -> List[Tuple[Dict[str, Any], bytes, int]]:
-        frames: List[Tuple[Dict[str, Any], bytes, int]] = []
+    def parse_frames(self) -> List[Tuple[Dict[str, Any], bytes]]:
+        frames: List[Tuple[Dict[str, Any], bytes]] = []
         while len(self.buf) >= _PREFIX.size:
             head_len, payload_len = _PREFIX.unpack(bytes(self.buf[: _PREFIX.size]))
             total = _PREFIX.size + head_len + payload_len
@@ -239,7 +242,7 @@ class _ConnState:
             head = bytes(self.buf[_PREFIX.size:_PREFIX.size + head_len])
             payload = bytes(self.buf[_PREFIX.size + head_len:total])
             del self.buf[:total]
-            frames.append((_unpack_header(head), payload, total))
+            frames.append((_unpack_header(head), payload))
         return frames
 
 
@@ -342,7 +345,8 @@ class SocketTransport:
     def send(
         self, client_id: str, header: Mapping[str, Any], payload: bytes = b""
     ) -> int:
-        """Send one frame to a connected silo; returns wire bytes.
+        """Send one frame to a connected silo; returns wire bytes.  Span
+        ``fl.send`` (counter ``send_bytes``).
 
         Raises ``ConnectionError`` when the silo is not connected or the
         send times out / fails — callers map that onto the §4.3 crash
@@ -353,7 +357,10 @@ class SocketTransport:
         sock = state.sock
         try:
             sock.settimeout(self.send_timeout_s)
-            return send_frame(sock, header, payload)
+            with spans.span("fl.send") as sp:
+                sp.nbytes = send_frame(sock, header, payload)
+            spans.add("send_bytes", sp.nbytes)
+            return sp.nbytes
         except (OSError, socket.timeout) as exc:
             self._drop(state)
             raise ConnectionError(
@@ -391,19 +398,27 @@ class SocketTransport:
             self._selector.register(conn, selectors.EVENT_READ, state)
 
     def _read(self, state: _ConnState, events: List[TransportEvent]) -> None:
+        # One read is span fl.recv.  Reads that continue a partial frame,
+        # or follow the last one within RECV_MERGE_S, extend its span, so
+        # a frame of any size adds one span, not one per chunk.
         closed = False
-        try:
-            chunk = state.sock.recv(_RECV_CHUNK)
-            if not chunk:
+        gap = math.inf if state.buf else spans.RECV_MERGE_S
+        with spans.span("fl.recv", merge_gap_s=gap) as sp:
+            try:
+                chunk = state.sock.recv(_RECV_CHUNK)
+                if not chunk:
+                    closed = True
+                else:
+                    state.buf.extend(chunk)
+                    sp.nbytes = len(chunk)
+            except BlockingIOError:
+                return
+            except OSError:
                 closed = True
-            else:
-                state.buf.extend(chunk)
-        except BlockingIOError:
-            return
-        except OSError:
-            closed = True
+            frames = state.parse_frames()
+        spans.add("recv_bytes", sp.nbytes)
 
-        for header, payload, wire in state.parse_frames():
+        for header, payload in frames:
             if state.client_id is None:
                 if header.get("kind") != MSG_HELLO or "client_id" not in header:
                     closed = True
@@ -417,9 +432,7 @@ class SocketTransport:
                 events.append(TransportEvent("joined", cid))
             else:
                 events.append(
-                    TransportEvent(
-                        "message", state.client_id, header, payload, wire
-                    )
+                    TransportEvent("message", state.client_id, header, payload)
                 )
 
         if closed:
@@ -592,7 +605,9 @@ def run_client_worker(
             if compressor is None:
                 compressor = ClientCompressor(spec)
     send_lock = threading.Lock()
-    jobs: "queue.Queue[Optional[Tuple[Dict[str, Any], bytes]]]" = queue.Queue()
+    jobs: "queue.Queue[Optional[Tuple[Dict[str, Any], bytes, spans.SpanLog]]]" = (
+        queue.Queue()
+    )
 
     def _send(header: Mapping[str, Any], payload: bytes = b"") -> None:
         with send_lock:
@@ -602,12 +617,13 @@ def run_client_worker(
         hook = getattr(client, "mangle_payload", None)
         return bytes(hook(body)) if callable(hook) else body
 
-    def _run_job(header: Mapping[str, Any], payload: bytes) -> None:
-        # One s_msg_train / s_msg_aggreg job.  Its device buffers (the
-        # received weights, the trained result) are locals, released when
-        # the job returns rather than held until the next one rebinds them.
-        kind = header.get("kind")
-        round_idx = int(header.get("round_idx", 0))
+    def _reply(
+        kind: Any, round_idx: int, payload: bytes
+    ) -> Tuple[Dict[str, Any], bytes]:
+        # The compute of one job and its reply's header and body.  Its
+        # device buffers (the received weights, the trained result) are
+        # locals, released when the job returns rather than held until
+        # the next one rebinds them.
         on_round = getattr(client, "on_round", None)
         if callable(on_round):
             on_round(round_idx, "train" if kind == MSG_S_TRAIN else "eval")
@@ -653,19 +669,31 @@ def run_client_worker(
                 body = serialize_update(update)
             else:
                 body = serialize_pytree(result.params)
+            return header_out, body
+        ev = client.evaluate(params)
+        return {
+            "kind": MSG_C_TEST,
+            "round_idx": round_idx,
+            "client_id": str(client.client_id),
+            "n_samples": int(ev.n_samples),
+        }, serialize_metrics(ev.metrics)
+
+    def _run_job(
+        header: Mapping[str, Any], payload: bytes, frame_log: spans.SpanLog
+    ) -> None:
+        # One s_msg_train / s_msg_aggreg job.  Span fl.job runs from the
+        # frame's first byte (fl.recv, timed on the receive loop) to the
+        # reply's serialization; the job's spans and counters ride in the
+        # reply header.
+        round_idx = int(header.get("round_idx", 0))
+        log = spans.SpanLog(str(client.client_id), round_idx)
+        recv_start = frame_log.spans[0].start_s if frame_log.spans else None
+        with spans.collect(log), spans.span("fl.job", start_s=recv_start):
+            log.merge(frame_log.to_wire(), parent=0)
+            header_out, body = _reply(header.get("kind"), round_idx, payload)
+        header_out.update(log.to_wire())
+        with spans.span("fl.send"):
             _send(header_out, _mangle(body))
-        else:
-            ev = client.evaluate(params)
-            _send(
-                {
-                    "kind": MSG_C_TEST,
-                    "round_idx": round_idx,
-                    "client_id": str(client.client_id),
-                    "n_samples": int(ev.n_samples),
-                    "eval_time_s": float(ev.eval_time_s),
-                },
-                _mangle(serialize_metrics(ev.metrics)),
-            )
 
     def _compute_loop() -> None:
         # A raising client IS the crash model: shut the socket down so
@@ -696,7 +724,9 @@ def run_client_worker(
     try:
         _send({"kind": MSG_HELLO, "client_id": str(client.client_id)})
         while True:
-            frame = recv_frame(sock)
+            frame_log = spans.SpanLog()
+            with spans.collect(frame_log):
+                frame = recv_frame(sock)
             if frame is None:
                 return
             header, payload = frame
@@ -715,7 +745,7 @@ def run_client_worker(
                     )
                 continue
             if kind in (MSG_S_TRAIN, MSG_S_AGGREG):
-                jobs.put((header, payload))
+                jobs.put((header, payload, frame_log))
     except Exception:  # noqa: BLE001 — crash-to-EOF is the §4.3 contract
         pass
     finally:
@@ -983,6 +1013,16 @@ class RecordedSchedule(ArrivalSchedule):
 # Live round driver
 # ---------------------------------------------------------------------------
 
+def _merge_silo_spans(
+    client_id: str, round_idx: int, header: Mapping[str, Any]
+) -> None:
+    """Fold the spans and counters a silo's reply header carries into
+    the round's log (bound on the driver thread by ``run``)."""
+    log = spans.bound()
+    if log is not None:
+        log.merge(header, where=client_id, round_idx=round_idx)
+
+
 @dataclasses.dataclass
 class _TrainOutcome:
     """One silo's physically-observed training phase for a round."""
@@ -1214,7 +1254,13 @@ class LiveRoundDriver:
         t_start = time.monotonic()
         records: List[RoundRecord] = []
         for round_idx in range(1, n_rounds + 1):
-            records.append(self._run_round(round_idx))
+            # The round's spans and counters: the driver's, and those the
+            # silos' replies carried in, merged as each reply is taken.
+            log = spans.SpanLog("driver", round_idx)
+            with spans.collect(log), spans.span("fl.round"):
+                record = self._run_round(round_idx)
+            record.spans, record.counters = log.spans, log.counters
+            records.append(record)
         if self.server_ckpt is not None:
             self.server_ckpt.wait_for_transfers()
         return FLRunResult(
@@ -1268,45 +1314,48 @@ class LiveRoundDriver:
                     forced[f.task] = f.kind
 
         # Training phase: s_msg_train out, c_msg_train back (measured).
-        s_train_payload = serialize_pytree(self.params)
         dispatched: List[str] = []
-        for cid in expected:
-            try:
-                self.transport.send(
-                    cid,
-                    {"kind": MSG_S_TRAIN, "round_idx": round_idx},
-                    s_train_payload,
-                )
-                dispatched.append(cid)
-            except ConnectionError:
-                self._drop_from_cohort(cid)
+        with spans.span("fl.dispatch"):
+            s_train_payload = serialize_pytree(self.params)
+            for cid in expected:
+                try:
+                    self.transport.send(
+                        cid,
+                        {"kind": MSG_S_TRAIN, "round_idx": round_idx},
+                        s_train_payload,
+                    )
+                    dispatched.append(cid)
+                except ConnectionError:
+                    self._drop_from_cohort(cid)
         if not dispatched:
             raise RuntimeError("every silo disconnected at dispatch")
 
-        outcomes = self._collect_train(
-            round_idx, dispatched, t0, s_train_payload, forced
-        )
+        with spans.span("fl.collect"):
+            outcomes = self._collect_train(
+                round_idx, dispatched, t0, s_train_payload, forced
+            )
 
-        t_agg = time.monotonic()
-        results = [
-            ClientResult(cid, o.params, o.n_samples, o.train_time_s)
-            for cid, o in outcomes.items()
-        ]
-        schedule = RecordedSchedule(
-            {cid: o.to_arrival(cid) for cid, o in outcomes.items()}
-        )
-        fold = self._engine.fold_round(
-            round_idx, results, schedule,
-            base_params=(
-                self.params
-                if (self.compression is not None or self.schema is not None)
-                else None
-            ),
-        )
-        self.fold_reports.append(fold)
-        self.params = fold.params
-        jax.block_until_ready(self.params)
-        agg_time = time.monotonic() - t_agg
+        with spans.span("fl.fold"):
+            t_agg = time.monotonic()
+            results = [
+                ClientResult(cid, o.params, o.n_samples, o.train_time_s)
+                for cid, o in outcomes.items()
+            ]
+            schedule = RecordedSchedule(
+                {cid: o.to_arrival(cid) for cid, o in outcomes.items()}
+            )
+            fold = self._engine.fold_round(
+                round_idx, results, schedule,
+                base_params=(
+                    self.params
+                    if (self.compression is not None or self.schema is not None)
+                    else None
+                ),
+            )
+            self.fold_reports.append(fold)
+            self.params = fold.params
+            jax.block_until_ready(self.params)
+            agg_time = time.monotonic() - t_agg
         train_time = time.monotonic() - t0
 
         # §4.4: consecutive reply timeouts escalate like deadline misses
@@ -1343,20 +1392,21 @@ class LiveRoundDriver:
 
         # Evaluation phase: s_msg_aggreg out, c_msg_test back.
         t1 = time.monotonic()
-        s_aggreg_payload = serialize_pytree(self.params)
         eval_targets: List[str] = []
-        for cid in self._cohort:
-            if not self.transport.is_live(cid):
-                continue
-            try:
-                self.transport.send(
-                    cid,
-                    {"kind": MSG_S_AGGREG, "round_idx": round_idx},
-                    s_aggreg_payload,
-                )
-                eval_targets.append(cid)
-            except ConnectionError:
-                self._drop_from_cohort(cid)
+        with spans.span("fl.fanout"):
+            s_aggreg_payload = serialize_pytree(self.params)
+            for cid in self._cohort:
+                if not self.transport.is_live(cid):
+                    continue
+                try:
+                    self.transport.send(
+                        cid,
+                        {"kind": MSG_S_AGGREG, "round_idx": round_idx},
+                        s_aggreg_payload,
+                    )
+                    eval_targets.append(cid)
+                except ConnectionError:
+                    self._drop_from_cohort(cid)
         # Chaos: driver-level eval-phase faults sever now — the silo
         # skips this round's metrics only; the stray-disconnect path
         # restarts it (cross-host when a scheduler is attached) so it
@@ -1370,9 +1420,10 @@ class LiveRoundDriver:
                 ):
                     eval_targets.remove(f.task)
                     self._handle_stray_disconnect(f.task)
-        metrics_by_cid, eval_n, c_test_bytes = self._collect_eval(
-            round_idx, eval_targets, t1
-        )
+        with spans.span("fl.collect_eval"):
+            metrics_by_cid, eval_n, c_test_bytes = self._collect_eval(
+                round_idx, eval_targets, t1
+            )
         if metrics_by_cid:
             order = sorted(metrics_by_cid)
             metrics = aggregate_metrics(
@@ -1813,6 +1864,7 @@ class LiveRoundDriver:
                     gd = ev.header.get("group_dense")
                     if isinstance(gd, Mapping):
                         o.group_dense = {str(k): int(v) for k, v in gd.items()}
+                    _merge_silo_spans(cid, round_idx, ev.header)
                     pending.discard(cid)
         return outcomes
 
@@ -1862,5 +1914,6 @@ class LiveRoundDriver:
                     }
                     eval_n[cid] = int(ev.header.get("n_samples", 0))
                     sizes.append(len(ev.payload))
+                    _merge_silo_spans(cid, round_idx, ev.header)
                     pending.discard(cid)
         return metrics_by_cid, eval_n, sizes
