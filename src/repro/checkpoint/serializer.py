@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import io
 import time
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +24,12 @@ _EXTENSION_DTYPES = {
     "float8_e4m3fn": ml_dtypes.float8_e4m3fn,
     "float8_e5m2": ml_dtypes.float8_e5m2,
 }
+
+
+# What a blob may arrive as: ``bytes`` from a serializer or a file, a
+# ``memoryview`` of a received frame's buffer (the live transport's
+# payloads).  Decoding reads it in place through the buffer protocol.
+BytesLike = Union[bytes, bytearray, memoryview]
 
 
 class DeserializationError(ValueError):
@@ -96,8 +102,9 @@ def serialize_pytree(tree: Any) -> bytes:
     return blob
 
 
-def deserialize_pytree(blob: bytes, like: Any) -> Any:
-    """Restore into the structure of `like` (paths must match).
+def deserialize_pytree(blob: BytesLike, like: Any) -> Any:
+    """Restore into the structure of `like` (paths must match); `blob`
+    is any bytes-like object and is read in place.
 
     Raises :class:`DeserializationError` when the blob is malformed
     (truncated msgpack, garbled entries, buffer/shape size mismatch) —

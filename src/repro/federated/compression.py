@@ -38,7 +38,7 @@ import msgpack
 import numpy as np
 
 from repro import spans
-from repro.checkpoint.serializer import DeserializationError
+from repro.checkpoint.serializer import BytesLike, DeserializationError
 
 # One quantization block per Pallas grid tile of the fused
 # dequantize-and-fold kernel (kernels/fedavg_reduce.BLOCK), so the (B,)
@@ -288,8 +288,9 @@ def serialize_update(update: CompressedUpdate) -> bytes:
     return packed
 
 
-def deserialize_update(payload: bytes) -> CompressedUpdate:
-    """Decode a compressed c_msg_train payload.
+def deserialize_update(payload: BytesLike) -> CompressedUpdate:
+    """Decode a compressed c_msg_train payload (any bytes-like object,
+    read in place).
 
     Raises :class:`~repro.checkpoint.serializer.DeserializationError` on
     any malformed, truncated, or internally inconsistent frame — the same
@@ -474,9 +475,10 @@ def serialize_structured(update: StructuredUpdate) -> bytes:
     return packed
 
 
-def deserialize_structured(payload: bytes) -> StructuredUpdate:
-    """Decode a structured c_msg_train payload (typed errors, like
-    :func:`deserialize_update`, so §4.3 re-request recovery applies)."""
+def deserialize_structured(payload: BytesLike) -> StructuredUpdate:
+    """Decode a structured c_msg_train payload (any bytes-like object,
+    read in place; typed errors, like :func:`deserialize_update`, so
+    §4.3 re-request recovery applies)."""
     try:
         obj = msgpack.unpackb(payload, raw=False)
     except Exception as exc:

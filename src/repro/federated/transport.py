@@ -87,6 +87,7 @@ import numpy as np
 from repro import spans
 from repro.checkpoint import resolve_freshest
 from repro.checkpoint.serializer import (
+    BytesLike,
     DeserializationError,
     deserialize_pytree,
     serialize_pytree,
@@ -148,14 +149,15 @@ MSG_PONG = "pong"
 # Frame = 8-byte prefix (header length, payload length, both u32 BE)
 # + msgpack header + raw payload (serialized pytree / metrics blob).
 _PREFIX = struct.Struct(">II")
-_RECV_CHUNK = 1 << 20
+
+_Frame = Tuple[Dict[str, Any], memoryview]
 
 
 def _pack_header(header: Mapping[str, Any]) -> bytes:
     return bytes(msgpack.packb(dict(header), use_bin_type=True))
 
 
-def _unpack_header(blob: bytes) -> Dict[str, Any]:
+def _unpack_header(blob: BytesLike) -> Dict[str, Any]:
     out = msgpack.unpackb(blob, raw=False)
     if not isinstance(out, dict):
         raise ValueError(f"malformed frame header: {out!r}")
@@ -163,45 +165,72 @@ def _unpack_header(blob: bytes) -> Dict[str, Any]:
 
 
 def send_frame(
-    sock: socket.socket, header: Mapping[str, Any], payload: bytes = b""
+    sock: socket.socket, header: Mapping[str, Any], payload: BytesLike = b""
 ) -> int:
-    """Write one frame; returns the bytes put on the wire (prefix incl.)."""
+    """Write one frame; returns the bytes put on the wire (prefix incl.).
+
+    The prefix, header and payload go out as one gathered write
+    (``sendmsg``), so the payload is never copied to join them, and the
+    stream is cut into segments as one ``sendall`` of the whole frame
+    would cut it: no small segment of its own for Nagle's algorithm to
+    hold the payload's tail behind."""
     head = _pack_header(header)
-    sock.sendall(_PREFIX.pack(len(head), len(payload)) + head + payload)
-    return _PREFIX.size + len(head) + len(payload)
+    parts = [memoryview(_PREFIX.pack(len(head), len(payload)) + head), memoryview(payload)]
+    total = len(parts[0]) + len(parts[1])
+    while parts:
+        sent = sock.sendmsg(parts)
+        while parts and sent >= len(parts[0]):
+            sent -= len(parts.pop(0))
+        if sent:
+            parts[0] = parts[0][sent:]
+    return total
 
 
-def _recv_exact(sock: socket.socket, n: int) -> Optional[bytes]:
-    """Blocking read of exactly n bytes; None on a clean EOF at a frame
-    boundary (mid-frame EOF raises ConnectionError)."""
-    buf = bytearray()
-    while len(buf) < n:
-        chunk = sock.recv(n - len(buf))
-        if not chunk:
-            if not buf:
-                return None
+def _frame_buffer(size: int) -> memoryview:
+    """A writable buffer for one frame's header and payload, left
+    unfilled: ``np.empty`` skips the zeroing that ``bytearray(size)``
+    does under the interpreter lock, and ``recv_into`` fills it."""
+    return memoryview(np.empty(size, np.uint8))
+
+
+def _split_frame(frame: memoryview, head_len: int) -> _Frame:
+    """The header and a zero-copy view of the payload of a full frame."""
+    return _unpack_header(frame[:head_len]), frame[head_len:]
+
+
+def _recv_all_into(sock: socket.socket, view: memoryview) -> int:
+    """Blocking fill of ``view``; returns the reads it took.  EOF before
+    it is full raises ConnectionError (the peer closed mid-frame)."""
+    reads = 0
+    while view:
+        n = sock.recv_into(view)
+        if not n:
             raise ConnectionError("connection closed mid-frame")
-        buf.extend(chunk)
-    return bytes(buf)
+        view = view[n:]
+        reads += 1
+    return reads
 
 
-def recv_frame(sock: socket.socket) -> Optional[Tuple[Dict[str, Any], bytes]]:
-    """Blocking read of one frame; None on clean EOF (peer closed).  The
-    read after the prefix arrives is span ``fl.recv`` (counter
-    ``recv_bytes``)."""
-    prefix = _recv_exact(sock, _PREFIX.size)
-    if prefix is None:
+def recv_frame(sock: socket.socket) -> Optional[_Frame]:
+    """Blocking read of one frame; None on a clean EOF at a frame
+    boundary (peer closed), ConnectionError on an EOF inside one.
+
+    The prefix sizes one buffer that ``recv_into`` fills in place; the
+    payload is a view of it.  The read after the prefix arrives is span
+    ``fl.recv`` (counters ``recv_bytes``, and ``recv_reads``: the reads
+    that filled frame bytes, the prefix's included)."""
+    prefix = bytearray(_PREFIX.size)
+    n = sock.recv_into(prefix)
+    if not n:
         return None
+    reads = 1 + _recv_all_into(sock, memoryview(prefix)[n:])
     head_len, payload_len = _PREFIX.unpack(prefix)
     with spans.span("fl.recv", nbytes=_PREFIX.size + head_len + payload_len) as sp:
-        head = _recv_exact(sock, head_len) if head_len else b""
-        if head is None:
-            raise ConnectionError("connection closed mid-frame")
-        payload = _recv_exact(sock, payload_len) if payload_len else b""
-        if payload is None:
-            raise ConnectionError("connection closed mid-frame")
+        frame = _frame_buffer(head_len + payload_len)
+        reads += _recv_all_into(sock, frame)
     spans.add("recv_bytes", sp.nbytes)
-    return _unpack_header(head), payload
+    spans.add("recv_reads", reads)
+    return _split_frame(frame, head_len)
 
 
 # ---------------------------------------------------------------------------
@@ -215,35 +244,65 @@ class TransportEvent:
     ``kind``: ``"message"`` (a complete frame from an identified client),
     ``"joined"`` (a worker's hello was accepted — first connect or a
     §4.3 restart rejoin), or ``"disconnect"`` (EOF/reset: the silo
-    crashed or shut down).
+    crashed or shut down).  A message's ``payload`` is bytes-like, a
+    view of the frame's receive buffer: consumers read it in place
+    (``msgpack.unpackb``, ``len``) and do not copy it into ``bytes``.
     """
 
     kind: str
     client_id: str
     header: Mapping[str, Any] = dataclasses.field(default_factory=dict)
-    payload: bytes = b""
+    payload: BytesLike = b""
 
 
 class _ConnState:
-    """Per-connection receive buffer + incremental frame parser."""
+    """Per-connection frame reader: the prefix, then one buffer of the
+    frame's exact size, each filled in place by ``recv_into``."""
 
     def __init__(self, sock: socket.socket) -> None:
         self.sock = sock
-        self.buf = bytearray()
         self.client_id: Optional[str] = None
+        self._prefix = bytearray(_PREFIX.size)
+        self._frame: Optional[memoryview] = None   # None: reading the prefix
+        self._head_len = 0
+        self._todo = memoryview(self._prefix)     # the unfilled rest
 
-    def parse_frames(self) -> List[Tuple[Dict[str, Any], bytes]]:
-        frames: List[Tuple[Dict[str, Any], bytes]] = []
-        while len(self.buf) >= _PREFIX.size:
-            head_len, payload_len = _PREFIX.unpack(bytes(self.buf[: _PREFIX.size]))
-            total = _PREFIX.size + head_len + payload_len
-            if len(self.buf) < total:
-                break
-            head = bytes(self.buf[_PREFIX.size:_PREFIX.size + head_len])
-            payload = bytes(self.buf[_PREFIX.size + head_len:total])
-            del self.buf[:total]
-            frames.append((_unpack_header(head), payload))
-        return frames
+    @property
+    def partial(self) -> bool:
+        """Whether some bytes of a frame have been read and not handed out."""
+        return self._frame is not None or len(self._todo) < _PREFIX.size
+
+    def read(self) -> Tuple[List[_Frame], int, int, bool]:
+        """Read what the nonblocking socket holds.  Returns the frames it
+        completed, the bytes and the reads that filled frame bytes, and
+        whether the peer closed (EOF or a socket error)."""
+        frames: List[_Frame] = []
+        nbytes = reads = 0
+        while True:
+            try:
+                n = self.sock.recv_into(self._todo)
+            except BlockingIOError:
+                return frames, nbytes, reads, False
+            except OSError:
+                return frames, nbytes, reads, True
+            if not n:
+                return frames, nbytes, reads, True
+            nbytes += n
+            reads += 1
+            self._todo = self._todo[n:]
+            if not self._todo:
+                self._advance(frames)
+
+    def _advance(self, frames: List[_Frame]) -> None:
+        # The prefix or the frame just filled up.
+        if self._frame is None:
+            self._head_len, payload_len = _PREFIX.unpack(self._prefix)
+            self._frame = self._todo = _frame_buffer(self._head_len + payload_len)
+            if self._todo:
+                return
+        frames.append(_split_frame(self._frame, self._head_len))
+        self._frame = None
+        self._todo = memoryview(self._prefix)
 
 
 class SocketTransport:
@@ -343,7 +402,7 @@ class SocketTransport:
 
     # -- sending -----------------------------------------------------------
     def send(
-        self, client_id: str, header: Mapping[str, Any], payload: bytes = b""
+        self, client_id: str, header: Mapping[str, Any], payload: BytesLike = b""
     ) -> int:
         """Send one frame to a connected silo; returns wire bytes.  Span
         ``fl.send`` (counter ``send_bytes``).
@@ -398,25 +457,14 @@ class SocketTransport:
             self._selector.register(conn, selectors.EVENT_READ, state)
 
     def _read(self, state: _ConnState, events: List[TransportEvent]) -> None:
-        # One read is span fl.recv.  Reads that continue a partial frame,
+        # One call is span fl.recv.  Calls that continue a partial frame,
         # or follow the last one within RECV_MERGE_S, extend its span, so
-        # a frame of any size adds one span, not one per chunk.
-        closed = False
-        gap = math.inf if state.buf else spans.RECV_MERGE_S
+        # a frame of any size adds one span, not one per read.
+        gap = math.inf if state.partial else spans.RECV_MERGE_S
         with spans.span("fl.recv", merge_gap_s=gap) as sp:
-            try:
-                chunk = state.sock.recv(_RECV_CHUNK)
-                if not chunk:
-                    closed = True
-                else:
-                    state.buf.extend(chunk)
-                    sp.nbytes = len(chunk)
-            except BlockingIOError:
-                return
-            except OSError:
-                closed = True
-            frames = state.parse_frames()
+            frames, sp.nbytes, reads, closed = state.read()
         spans.add("recv_bytes", sp.nbytes)
+        spans.add("recv_reads", reads)
 
         for header, payload in frames:
             if state.client_id is None:
@@ -605,11 +653,11 @@ def run_client_worker(
             if compressor is None:
                 compressor = ClientCompressor(spec)
     send_lock = threading.Lock()
-    jobs: "queue.Queue[Optional[Tuple[Dict[str, Any], bytes, spans.SpanLog]]]" = (
+    jobs: "queue.Queue[Optional[Tuple[Dict[str, Any], BytesLike, spans.SpanLog]]]" = (
         queue.Queue()
     )
 
-    def _send(header: Mapping[str, Any], payload: bytes = b"") -> None:
+    def _send(header: Mapping[str, Any], payload: BytesLike = b"") -> None:
         with send_lock:
             send_frame(sock, header, payload)
 
@@ -618,7 +666,7 @@ def run_client_worker(
         return bytes(hook(body)) if callable(hook) else body
 
     def _reply(
-        kind: Any, round_idx: int, payload: bytes
+        kind: Any, round_idx: int, payload: BytesLike
     ) -> Tuple[Dict[str, Any], bytes]:
         # The compute of one job and its reply's header and body.  Its
         # device buffers (the received weights, the trained result) are
@@ -679,7 +727,7 @@ def run_client_worker(
         }, serialize_metrics(ev.metrics)
 
     def _run_job(
-        header: Mapping[str, Any], payload: bytes, frame_log: spans.SpanLog
+        header: Mapping[str, Any], payload: BytesLike, frame_log: spans.SpanLog
     ) -> None:
         # One s_msg_train / s_msg_aggreg job.  Span fl.job runs from the
         # frame's first byte (fl.recv, timed on the receive loop) to the

@@ -2,7 +2,9 @@
 semantics, and the span tree a loopback round records on its
 ``RoundRecord`` — dense and int8 messages of several MB, two silos."""
 import collections
+import socket
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +13,9 @@ import pytest
 from repro import spans
 from repro.core import Experiment
 from repro.optim import make_optimizer
-from test_transport import ArraySilo, PacedClient, chain_replies, trace_signature
+from repro.federated import SocketTransport
+from repro.federated.transport import _ConnState
+from test_transport import ArraySilo, PacedClient, _wire, chain_replies, trace_signature
 
 # ---------------------------------------------------------------------------
 # The recorder
@@ -79,6 +83,45 @@ def test_recv_reads_merge_within_the_gap_or_when_joined():
             pass
     assert [(s.name, s.nbytes) for s in log.spans] == [
         ("fl.collect", 0), ("fl.recv", 7), ("fl.deserialize", 0), ("fl.recv", 5), ("fl.recv", 7)]
+
+
+class _CountingSocket:
+    """A socket whose ``recv_into`` calls are counted."""
+
+    def __init__(self, sock):
+        self.sock = sock
+        self.calls = 0
+
+    def recv_into(self, buf):
+        self.calls += 1
+        return self.sock.recv_into(buf)
+
+
+def test_a_frame_read_in_many_reads_is_one_recv_span():
+    frame = _wire({"kind": "c_msg_train", "round_idx": 1}, bytes(range(256)) * 4096)
+    pieces = [frame[i:i + 100_000] for i in range(0, len(frame), 100_000)]
+    a, b = socket.socketpair()
+    b.setblocking(False)
+    sock = _CountingSocket(b)
+    state = _ConnState(sock)
+    state.client_id = "c0"
+    transport = SocketTransport()
+    log, events, calls = spans.SpanLog(), [], 0
+    try:
+        with spans.collect(log):
+            for piece in pieces:     # a read per piece, further apart than RECV_MERGE_S
+                a.sendall(piece)
+                time.sleep(3 * spans.RECV_MERGE_S)
+                transport._read(state, events)
+                calls += 1
+    finally:
+        a.close()
+        b.close()
+    assert calls == len(pieces) > 5
+    assert [ev.kind for ev in events] == ["message"] and len(events[0].payload) == 1 << 20
+    assert [(s.name, s.nbytes) for s in log.spans] == [("fl.recv", len(frame))]
+    assert log.counters["recv_bytes"] == len(frame)
+    assert 1 <= log.counters["recv_reads"] <= sock.calls
 
 
 def test_back_dated_span_and_merge_reindex_parents():
@@ -199,6 +242,9 @@ def test_byte_counters_match_the_message_log(live):
         sends = [s.nbytes for s in rec.spans if s.name == "fl.send"]
         assert sum(sends) == c["send_bytes"]
         assert sum(s.nbytes for s in rec.spans if s.name == "fl.recv") == c["recv_bytes"]
+        # Eight frames a round (four each way), each read as its prefix
+        # and then the rest: at least two reads a frame.
+        assert 2 * 8 <= c["recv_reads"] <= c["recv_bytes"]
         # H2D: each silo's two received weight sets, and the driver's
         # dense replies or the int8 payloads its fold moved.  D2H: the
         # driver's two messages, and the silos' weights or encoder flats.

@@ -3,11 +3,14 @@ in-process AsyncFLServer (same params, same trace vocabulary modulo
 timestamps), §4.3 crash-mid-round recovery, reply-timeout mapping onto
 exclusion + §4.4 StragglerEscalated, deadline carry-over on measured
 arrivals, and the measured-message-size feedback into CostModel."""
+import select
 import socket
+import struct
 import threading
 import time
 
 import jax.numpy as jnp
+import msgpack
 import numpy as np
 import pytest
 
@@ -32,7 +35,7 @@ from repro.federated import (
     ThreadWorkerPool,
 )
 from repro.federated.async_server import ArrivalSchedule, ClientArrival
-from repro.federated.transport import recv_frame, send_frame
+from repro.federated.transport import _ConnState, recv_frame, send_frame
 from repro.optim import make_optimizer
 
 
@@ -178,6 +181,137 @@ def test_frame_roundtrip_over_socketpair():
         assert wire == 8 + (wire - 8 - len(payload)) + len(payload)
         a.close()
         assert recv_frame(b) is None  # clean EOF at a frame boundary
+    finally:
+        a.close()
+        b.close()
+
+
+def _wire(header, payload):
+    """The frame layout, spelled out: u32 BE header and payload lengths,
+    the msgpack header, the raw payload."""
+    head = msgpack.packb(header, use_bin_type=True)
+    return struct.pack(">II", len(head), len(payload)) + head + payload
+
+
+_MSG = {"kind": "c_msg_train", "round_idx": 3, "client_id": "c0", "n_samples": 17}
+_HELLO = {"kind": "hello", "client_id": "c0"}
+_BIG = np.random.default_rng(7).integers(0, 256, 5 << 20, np.uint8).tobytes()
+
+
+def _pieces(blob, *cuts):
+    edges = [0, *cuts, len(blob)]
+    return [blob[a:b] for a, b in zip(edges, edges[1:])]
+
+
+# name -> (writes, expected frames, whether the peer closes mid-frame)
+_RECV_CASES = {
+    "prefix_split": (_pieces(_wire(_MSG, b"abc"), 3, 5), [(_MSG, b"abc")], False),
+    "header_split": (_pieces(_wire(_MSG, b"abc"), 10, 14), [(_MSG, b"abc")], False),
+    "payload_0": ([_wire(_MSG, b"")], [(_MSG, b"")], False),
+    "payload_1": ([_wire(_MSG, b"\x07")], [(_MSG, b"\x07")], False),
+    "payload_5mib": (_pieces(_wire(_MSG, _BIG), 5, 60, 1 << 20, 3 << 20),
+                     [(_MSG, _BIG)], False),
+    "two_frames_one_write": ([_wire(_MSG, b"one") + _wire({**_MSG, "round_idx": 4}, b"two")],
+                             [(_MSG, b"one"), ({**_MSG, "round_idx": 4}, b"two")], False),
+    "hello_then_message": ([_wire(_HELLO, b"") + _wire(_MSG, b"xyz")],
+                           [(_HELLO, b""), (_MSG, b"xyz")], False),
+    "eof_mid_prefix": ([_wire(_MSG, b"abc")[:5]], [], True),
+    "eof_mid_payload": ([_wire(_MSG, b"one"), _wire(_MSG, _BIG)[: 2 << 20]],
+                        [(_MSG, b"one")], True),
+    "clean_eof": ([_wire(_MSG, b"abc"), _wire(_MSG, b"")],
+                  [(_MSG, b"abc"), (_MSG, b"")], False),
+}
+
+
+def _dribble(sock, writes):
+    """Write each piece on its own, a pause apart, then close."""
+    def run():
+        for piece in writes:
+            sock.sendall(piece)
+            time.sleep(0.005)
+        sock.close()
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    return t
+
+
+def _read_with_recv_frame(sock, n_frames):
+    frames = [recv_frame(sock) for _ in range(n_frames)]
+    try:
+        end = "clean" if recv_frame(sock) is None else "frame"
+    except ConnectionError:
+        end = "mid-frame"
+    return frames, end
+
+
+def _read_with_driver(sock, hello):
+    # The driver's nonblocking reader.  Without a hello in the case the
+    # connection is taken as already identified.
+    transport = SocketTransport()
+    sock.setblocking(False)
+    state = _ConnState(sock)
+    if not hello:
+        state.client_id = "c0"
+    events = []
+    deadline = time.monotonic() + 30.0
+    while not events or events[-1].kind != "disconnect":
+        assert time.monotonic() < deadline
+        select.select([sock], [], [], 1.0)
+        transport._read(state, events)
+    assert all(ev.client_id == "c0" for ev in events)
+    return [(_HELLO, b"") if ev.kind == "joined" else (ev.header, ev.payload)
+            for ev in events[:-1]]
+
+
+@pytest.mark.parametrize("path", ["recv_frame", "driver"])
+@pytest.mark.parametrize("case", list(_RECV_CASES))
+def test_frame_reads_in_pieces(case, path):
+    """Frames written in pieces, or several to a write, come out whole on
+    the worker's blocking reader and the driver's nonblocking one; an
+    EOF inside a frame is an error (a disconnect, to the driver)."""
+    writes, want, mid_frame = _RECV_CASES[case]
+    a, b = socket.socketpair()
+    try:
+        writer = _dribble(a, writes)
+        if path == "recv_frame":
+            got, end = _read_with_recv_frame(b, len(want))
+            assert end == ("mid-frame" if mid_frame else "clean")
+        else:
+            got = _read_with_driver(b, hello=bool(want) and want[0][0] == _HELLO)
+        writer.join(timeout=10.0)
+        assert len(got) == len(want)
+        for (header, payload), (want_header, want_payload) in zip(got, want):
+            assert header == want_header
+            assert len(payload) == len(want_payload)
+            assert payload == want_payload
+    finally:
+        a.close()
+        b.close()
+
+
+@pytest.mark.parametrize("timeout", [None, 10.0], ids=["blocking", "timeout"])
+@pytest.mark.parametrize("size", [300, 3 << 20], ids=["small", "3mib"])
+def test_send_frame_writes_the_frame_layout(size, timeout):
+    # With a timeout (the driver's sends) the socket takes a frame in
+    # partial writes; blocking (the silos'), in one.
+    payload = bytes(range(256)) * (size // 256) + b"\x01" * (size % 256)
+    header = {"kind": "s_msg_train", "round_idx": 1}
+    a, b = socket.socketpair()
+    a.settimeout(timeout)
+    try:
+        sent = []
+        t = threading.Thread(target=lambda: (sent.append(send_frame(a, header, payload)),
+                                             a.close()), daemon=True)
+        t.start()
+        want = _wire(header, payload)
+        raw = bytearray()
+        while len(raw) <= len(want) and (chunk := b.recv(1 << 20)):
+            raw += chunk
+        assert bytes(raw) == want
+        t.join(timeout=10.0)
+        assert not t.is_alive()
+        assert sent == [len(raw)]
     finally:
         a.close()
         b.close()
