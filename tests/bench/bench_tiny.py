@@ -1,6 +1,10 @@
 """Tiny sizes of the benchmark's configurations, for CPU tests.
 
-Widths are cut here only; the cells run the published ones."""
+Widths are cut here only; the cells run the published ones.  Each
+configuration's sizes are a data file of its own,
+``tests/bench/sizes/<config>.json``: ``{"tiny": {...}, "control": {...}}``,
+overrides deep-merged into ``bench/configs/<config>.json``, so a new
+configuration needs no edit here."""
 import copy
 import os
 import sys
@@ -14,33 +18,36 @@ import jax  # noqa: E402
 
 from bench import common, harness  # noqa: E402
 
-TINY = {
-    "til_vgg16": lambda c: (
-        c["model"].update(image_size=16, stages=[[8, 1], [16, 1]], fc_width=32),
-        c["silos"].update(train=[48, 48, 40, 48], test=[20, 20, 20, 17])),
-    "shakespeare_lstm": lambda c: (
-        c["model"].update(hidden=32, seq_len=12),
-        c["silos"].update(train=[40, 50, 35], test=[10, 12, 7])),
-}
-
-
-# The bf16 control separates from float32 only where updates pile up on
-# wide layers: VGG16 at its published widths on 32x32 images, 10 steps.
-CONTROL = {
-    "til_vgg16": lambda c: (
-        c["model"].update(image_size=32),
-        c["silos"].update(train=[160, 160], test=[16, 16])),
-    "shakespeare_lstm": TINY["shakespeare_lstm"],
-}
+SIZES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "sizes")
+TINY = "tiny"
+CONTROL = "control"     # sizes at which the bf16 control separates from float32
 
 _CONFIG = common.config
 
 
+def merge(base, over):
+    """``base`` with ``over`` laid on it: a dict merges key by key, any
+    other value (lists included) replaces."""
+    out = dict(base)
+    for k, v in over.items():
+        out[k] = merge(base[k], v) if isinstance(v, dict) and isinstance(base.get(k), dict) else v
+    return out
+
+
+def sizes_of(name):
+    path = os.path.join(SIZES, f"{name}.json")
+    if not os.path.exists(path):
+        raise FileNotFoundError(
+            f"no CPU test sizes for configuration {name!r}: add {path} holding "
+            '{"tiny": {...}, "control": {...}}, overrides of keys of its '
+            "bench/configs JSON that cut it to CPU size (tiny) and to the size at "
+            "which the bf16 control fails its cell's limits (control)")
+    return common.load_json(path)
+
+
 def config(name, sizes=TINY):
     cfg, module = _CONFIG(name)
-    cfg = copy.deepcopy(cfg)
-    sizes[name](cfg)
-    return cfg, module
+    return merge(copy.deepcopy(cfg), sizes_of(name)[sizes]), module
 
 
 # (config, traffic) of each cell in BENCHMARK.json.

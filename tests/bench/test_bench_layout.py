@@ -11,7 +11,9 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_tiny  # noqa: E402
 from bench import common, harness  # noqa: E402
 
 BENCH = common.benchmark()
@@ -75,6 +77,54 @@ def test_a_new_metric_traffic_or_cell_file_needs_no_edit(tmp_path, monkeypatch):
     assert common.cell("til_fp16")["limits"] == {"change_gap": 0.1}
     after = {p: open(os.path.join(common.BENCH, p)).read() for p in before}
     assert before == after
+
+
+def _within(over, base):
+    """The override's keys that ``base`` does not have, nested as a path."""
+    out = []
+    for k, v in over.items():
+        if k not in base:
+            out.append(k)
+        elif isinstance(v, dict):
+            out += [f"{k}.{x}" for x in _within(v, base[k])]
+    return out
+
+
+def test_every_configuration_has_cpu_sizes_that_only_cut():
+    for c in BENCH["configs"]:
+        data, _ = common.config(c["name"])
+        sizes = bench_tiny.sizes_of(c["name"])
+        for kind in (bench_tiny.TINY, bench_tiny.CONTROL):
+            assert isinstance(sizes.get(kind), dict), (c["name"], kind)
+            assert not _within(sizes[kind], data), (c["name"], kind, _within(sizes[kind], data))
+
+
+def test_a_configuration_without_cpu_sizes_names_the_missing_file(tmp_path, monkeypatch):
+    monkeypatch.setattr(bench_tiny, "SIZES", str(tmp_path))
+    with pytest.raises(FileNotFoundError) as err:
+        bench_tiny.config("shakespeare_lstm")
+    assert str(tmp_path / "shakespeare_lstm.json") in str(err.value)
+    assert '"tiny"' in str(err.value) and '"control"' in str(err.value)
+
+
+@pytest.mark.parametrize("name, kind, model, silos", [
+    ("til_vgg16", "tiny", {"image_size": 16, "stages": [[8, 1], [16, 1]], "fc_width": 32},
+     {"train": [48, 48, 40, 48], "test": [20, 20, 20, 17], "dirichlet_alpha": 0.5}),
+    ("til_vgg16", "control", {"image_size": 32},
+     {"train": [160, 160], "test": [16, 16], "dirichlet_alpha": 0.5}),
+    ("shakespeare_lstm", "tiny", {"hidden": 32, "seq_len": 12},
+     {"scale": 0.035, "train": [40, 50, 35], "test": [10, 12, 7]}),
+    ("shakespeare_lstm", "control", {"hidden": 32, "seq_len": 12},
+     {"scale": 0.035, "train": [40, 50, 35], "test": [10, 12, 7]}),
+])
+def test_cpu_sizes_merge_into_the_configuration(name, kind, model, silos):
+    full, _ = common.config(name)
+    cfg, _ = bench_tiny.config(name, kind)
+    assert cfg["model"] == {**full["model"], **model}
+    assert cfg["silos"] == silos
+    assert {k: v for k, v in cfg.items() if k not in ("model", "silos")} == \
+        {k: v for k, v in full.items() if k not in ("model", "silos")}
+    assert common.config(name)[0] == full      # the configuration's own dict is untouched
 
 
 class _Clock:
