@@ -4,8 +4,12 @@ Each client receives the global weights, runs `local_epochs` of SGD/AdamW
 over its silo, and returns (updated weights, n_samples, wall time). The
 evaluation phase runs the silo's test split and returns scalar metrics.
 
-The train step is jitted once per (model, optimizer) pair and reused
-across rounds — like a real client process would.
+The train step is compiled once per (loss, optimizer) pair and batch
+shape, shared by every client that trains with that pair, and reused
+across rounds — like a real client process would.  From a round's second
+step on, the step donates the weights and optimizer state it is handed
+back, so that a silo holds one copy of each on the device while its
+steps run: the new values are written over the old.
 
 With wire compression enabled the client also owns its error-feedback
 residual (:class:`~repro.federated.compression.ClientCompressor`): the
@@ -20,7 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,20 +94,40 @@ class FLClient:
             if spec is not None:
                 self.compressor = ClientCompressor(spec)
 
-        @jax.jit
-        def train_step(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            params, opt_state = optimizer.update(grads, opt_state, params)
-            return params, opt_state, loss
-
-        self._train_step = train_step
+        # The step's last (weights, optimizer state), which the next call
+        # may donate; batch signatures whose programs are compiled.
+        self._own: Optional[Tuple[Any, Any]] = None
+        self._compiled: Set[Any] = set()
+        self._train_step = self._step
         self._jit_eval = jax.jit(eval_fn) if eval_fn is not None else None
+
+    def _step(self, params: Any, opt_state: Any, batch: Any) -> Tuple[Any, Any, Any]:
+        """One local step.  It donates ``params`` and ``opt_state`` only
+        where both are what its previous call returned: never the
+        received weights or ``optimizer.init``'s state, which a caller
+        may still hold, nor a state that a wrapper around this step
+        handed back in place of the one returned.  Both programs are
+        compiled at a batch signature's first call, so a round's later
+        steps compile nothing."""
+        args = (self.loss_fn, self.optimizer, params, opt_state, batch)
+        leaves, treedef = jax.tree.flatten(batch)
+        sig = (treedef, tuple((np.shape(x), np.result_type(x)) for x in leaves))
+        if sig not in self._compiled:
+            _STEP.lower(*args).compile()
+            _DONATING_STEP.lower(*args).compile()
+            self._compiled.add(sig)
+        own = self._own is not None and params is self._own[0] and opt_state is self._own[1]
+        out = (_DONATING_STEP if own else _STEP)(*args)
+        self._own = out[:2]
+        return out
 
     # -- training phase ------------------------------------------------------
     def train(self, global_params: Any) -> ClientResult:
         """Local training: span ``fl.train``, with counters ``step_calls``
-        and ``step_dispatch_s`` (host time inside the step calls) and the
-        closing ``block_until_ready`` as span ``fl.drain``."""
+        and ``step_dispatch_s`` (host time inside the step calls), the
+        closing ``block_until_ready`` as span ``fl.drain``, and after it
+        counter ``hbm_in_use_bytes`` (the device's bytes in use, where
+        the backend reports memory)."""
         with spans.span("fl.train"):
             t0 = time.monotonic()
             params = global_params
@@ -121,19 +145,25 @@ class FLClient:
             last_loss = None
             calls = 0
             dispatch_s = 0.0
-            for epoch in range(self.local_epochs):
-                for raw in self.silo.batches(self.batch_size, split="train"):
-                    batch = self.batch_fn(raw)
-                    t_call = time.perf_counter()
-                    params, opt_state, last_loss = self._train_step(params, opt_state, batch)
-                    dispatch_s += time.perf_counter() - t_call
-                    calls += 1
-                    if epoch == 0:
-                        n_first_epoch += _batch_count(raw)
+            try:
+                for epoch in range(self.local_epochs):
+                    for raw in self.silo.batches(self.batch_size, split="train"):
+                        batch = self.batch_fn(raw)
+                        t_call = time.perf_counter()
+                        params, opt_state, last_loss = self._train_step(params, opt_state, batch)
+                        dispatch_s += time.perf_counter() - t_call
+                        calls += 1
+                        if epoch == 0:
+                            n_first_epoch += _batch_count(raw)
+            finally:
+                self._own = None     # the result is the caller's: hold and donate none of it
             spans.add("step_calls", calls)
             spans.add("step_dispatch_s", dispatch_s)
             with spans.span("fl.drain"):
                 jax.block_until_ready(last_loss)
+            stats = _device_of(params).memory_stats()
+            if stats is not None:
+                spans.add("hbm_in_use_bytes", stats["bytes_in_use"])
             return ClientResult(
                 client_id=self.client_id,
                 params=params,
@@ -183,6 +213,26 @@ class FLClient:
                 n_samples=n,
                 eval_time_s=time.monotonic() - t0,
             )
+
+
+def train_step(loss_fn: Callable, optimizer: Any, params: Any, opt_state: Any,
+               batch: Any) -> Tuple[Any, Any, Any]:
+    """One optimizer step on one batch: (new params, new state, loss)."""
+    loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+    params, opt_state = optimizer.update(grads, opt_state, params)
+    return params, opt_state, loss
+
+
+_STEP = jax.jit(train_step, static_argnums=(0, 1))
+_DONATING_STEP = jax.jit(train_step, static_argnums=(0, 1), donate_argnums=(2, 3))
+
+
+def _device_of(tree: Any) -> Any:
+    """The device of the tree's first device array, else the default one."""
+    for leaf in jax.tree.leaves(tree):
+        if isinstance(leaf, jax.Array):
+            return next(iter(leaf.devices()))
+    return jax.devices()[0]
 
 
 def _batch_count(raw) -> int:
