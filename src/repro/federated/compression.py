@@ -31,9 +31,12 @@ the fused Pallas dequantize-and-fold kernel (``dequant_fold``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional, Tuple, Union
 
+import jax
+import jax.numpy as jnp
 import msgpack
 import numpy as np
 
@@ -155,6 +158,61 @@ def _num_blocks(total_elems: int) -> int:
     return -(-total_elems // QBLOCK)
 
 
+def _quantize(x: Any, codec: str) -> Tuple[Any, Any, Any]:
+    """The quantizer: ``(nb, QBLOCK)`` fp32 blocks to the codec's codes,
+    its per-block scales (``None`` for ``fp16``) and the dequantized
+    blocks.  ``int8`` is symmetric per block, ``scale = absmax / 127``,
+    round half to even, an all-zero block coded 0."""
+    if codec == "fp16":
+        data = x.astype(jnp.float16)
+        return data, None, data.astype(jnp.float32)
+    # The barrier keeps 127 from being a literal, which the compiler
+    # would turn into a multiply by its rounded reciprocal: the scale
+    # stays the divided absmax / 127 of the wire format's definition.
+    scales = jnp.max(jnp.abs(x), axis=1) / jax.lax.optimization_barrier(jnp.float32(127.0))
+    safe = jnp.where(scales > 0.0, scales, jnp.float32(1.0))
+    q = jnp.clip(jnp.round(x / safe[:, None]), -127.0, 127.0)
+    q = jnp.where((scales == 0.0)[:, None], 0.0, q).astype(jnp.int8)
+    return q, scales, q.astype(jnp.float32) * scales[:, None]
+
+
+def _blocks(flat: Any) -> Any:
+    """A flat fp32 vector zero-padded to whole ``(nb, QBLOCK)`` blocks."""
+    n = flat.shape[0]
+    return jnp.pad(flat, (0, _num_blocks(n) * QBLOCK - n)).reshape(-1, QBLOCK)
+
+
+@functools.partial(jax.jit, static_argnames="codec")
+def _quantize_flat(flat: Any, codec: str) -> Tuple[Any, Any]:
+    data, scales, _ = _quantize(_blocks(flat), codec)
+    return data.reshape(-1)[: flat.shape[0]], scales
+
+
+@functools.partial(jax.jit, static_argnames="codec", donate_argnames="residual")
+def _encode_update(
+    global_params: Any, local_params: Any, residual: Any, codec: str
+) -> Tuple[Any, Any, Any]:
+    """One client's update encoded on its device: ``local - global`` per
+    leaf (never two whole flat vectors at once), flattened and padded to
+    blocks, plus the error-feedback ``residual`` (``None``: none kept),
+    quantized.  Returns the codes of the ``n`` elements, the scales and
+    the new residual ``x - dequantized`` (``None`` without one)."""
+    deltas = [
+        jnp.ravel(loc.astype(jnp.float32) - glob.astype(jnp.float32))
+        for glob, loc in zip(jax.tree.leaves(global_params), jax.tree.leaves(local_params))
+    ]
+    flat = jnp.concatenate(deltas)
+    x = _blocks(flat)
+    if residual is not None:
+        x = x + residual
+    data, scales, deq = _quantize(x, codec)
+    return (
+        data.reshape(-1)[: flat.shape[0]],
+        scales,
+        None if residual is None else x - deq,
+    )
+
+
 def compress(
     flat: np.ndarray,
     spec: CompressionSpec,
@@ -162,22 +220,18 @@ def compress(
 ) -> CompressedUpdate:
     """Compress a dense fp32 vector (a flattened delta) with ``spec``.
 
-    Pure numpy and deterministic, so the virtual-clock server and the
-    live socket workers produce bit-identical updates for the same
-    inputs (trace/params parity across bus drivers).  ``base_round``
-    tags the update with the round whose global weights the delta was
-    taken against (see :class:`CompressedUpdate`).
+    Deterministic, so the virtual-clock server and the live socket
+    workers produce bit-identical updates for the same inputs
+    (trace/params parity across bus drivers).  ``int8`` and ``fp16``
+    run the device quantizer that :class:`ClientCompressor` runs;
+    ``topk`` selects on the host.  ``base_round`` tags the update with
+    the round whose global weights the delta was taken against (see
+    :class:`CompressedUpdate`).
     """
     vec = np.ascontiguousarray(np.asarray(flat, dtype=np.float32).reshape(-1))
     n = int(vec.size)
     if n == 0:
         raise ValueError("cannot compress an empty update")
-
-    if spec.codec == "fp16":
-        return CompressedUpdate(
-            codec="fp16", total_elems=n, data=vec.astype(np.float16),
-            base_round=base_round,
-        )
 
     if spec.codec == "topk":
         k = topk_count(n, spec.k_frac)
@@ -195,18 +249,10 @@ def compress(
             base_round=base_round,
         )
 
-    # int8: symmetric per-QBLOCK scales, scale = absmax / 127.
-    nb = _num_blocks(n)
-    padded = np.zeros(nb * QBLOCK, dtype=np.float32)
-    padded[:n] = vec
-    blocks = padded.reshape(nb, QBLOCK)
-    absmax = np.max(np.abs(blocks), axis=1)
-    scales = (absmax / 127.0).astype(np.float32)
-    safe = np.where(scales > 0.0, scales, np.float32(1.0))
-    q = np.clip(np.rint(blocks / safe[:, None]), -127, 127).astype(np.int8)
-    q[scales == 0.0] = 0
+    data, scales = _quantize_flat(jnp.asarray(vec), spec.codec)
     return CompressedUpdate(
-        codec="int8", total_elems=n, data=q.reshape(-1)[:n], scales=scales,
+        codec=spec.codec, total_elems=n, data=np.asarray(data),
+        scales=None if scales is None else np.asarray(scales),
         base_round=base_round,
     )
 
@@ -578,6 +624,11 @@ class ClientCompressor:
         u_t   = compress(e_t)
         residual_t = e_t - decompress(u_t)
 
+    ``int8`` and ``fp16`` run all of it as one program on the device the
+    weights live on, and the residual stays there, padded to whole
+    :data:`QBLOCK` blocks: only the codes and scales come to the host.
+    ``topk`` selects on the host, with a host residual.
+
     The residual lives with the client (worker) — a restarted or replaced
     worker starts with a zero residual, which only costs a little extra
     compression error on its next update, never correctness.
@@ -585,7 +636,7 @@ class ClientCompressor:
 
     def __init__(self, spec: CompressionSpec) -> None:
         self.spec = spec
-        self._residual: Optional[np.ndarray] = None
+        self._residual: Any = None
 
     def encode(
         self,
@@ -598,23 +649,55 @@ class ClientCompressor:
         ``base_round`` tags the update with the round those globals
         belong to, so the aggregator can refuse to fold it against any
         other base (see :class:`CompressedUpdate`).  Counters ``d2h_s``/
-        ``d2h_bytes``: the two flattened weight vectors' copy to the host."""
+        ``d2h_bytes``: the copy to the host of the codes and scales
+        (``topk``: of the two flattened weight vectors); ``encode_device``
+        counts the encodes run on the device."""
+        with spans.span("fl.encode") as sp:
+            if self.spec.codec == "topk":
+                update = self._encode_on_host(global_params, local_params, base_round)
+            else:
+                update = self._encode_on_device(global_params, local_params, base_round)
+            sp.nbytes = update.dense_bytes
+        return update
+
+    def _encode_on_device(
+        self, global_params: Any, local_params: Any, base_round: Optional[int]
+    ) -> CompressedUpdate:
+        if self.spec.error_feedback and self._residual is None:
+            n = sum(int(np.size(leaf)) for leaf in jax.tree.leaves(global_params))
+            self._residual = jnp.zeros((_num_blocks(n), QBLOCK), jnp.float32)
+        data, scales, residual = _encode_update(
+            global_params, local_params, self._residual, codec=self.spec.codec
+        )
+        self._residual = residual
+        t0 = time.perf_counter()
+        data = np.asarray(data)
+        scales = None if scales is None else np.asarray(scales)
+        spans.add("d2h_s", time.perf_counter() - t0)
+        spans.add("d2h_bytes", data.nbytes + (0 if scales is None else scales.nbytes))
+        spans.add("encode_device", 1)
+        return CompressedUpdate(
+            codec=self.spec.codec, total_elems=int(data.size), data=data,
+            scales=scales, base_round=base_round,
+        )
+
+    def _encode_on_host(
+        self, global_params: Any, local_params: Any, base_round: Optional[int]
+    ) -> CompressedUpdate:
         from repro.federated.agg_engine import plan_for
 
-        with spans.span("fl.encode") as sp:
-            plan = plan_for(global_params)
-            t0 = time.perf_counter()
-            g = np.asarray(plan.flatten(global_params), dtype=np.float32)
-            p = np.asarray(plan.flatten(local_params), dtype=np.float32)
-            spans.add("d2h_s", time.perf_counter() - t0)
-            spans.add("d2h_bytes", g.nbytes + p.nbytes)
-            delta = p - g
-            if self.spec.error_feedback and self._residual is not None:
-                delta = delta + self._residual
-            update = compress(delta, self.spec, base_round=base_round)
-            if self.spec.error_feedback:
-                self._residual = delta - decompress(update)
-            sp.nbytes = p.nbytes
+        plan = plan_for(global_params)
+        t0 = time.perf_counter()
+        g = np.asarray(plan.flatten(global_params), dtype=np.float32)
+        p = np.asarray(plan.flatten(local_params), dtype=np.float32)
+        spans.add("d2h_s", time.perf_counter() - t0)
+        spans.add("d2h_bytes", g.nbytes + p.nbytes)
+        delta = p - g
+        if self.spec.error_feedback and self._residual is not None:
+            delta = delta + self._residual
+        update = compress(delta, self.spec, base_round=base_round)
+        if self.spec.error_feedback:
+            self._residual = delta - decompress(update)
         return update
 
     def reset(self) -> None:

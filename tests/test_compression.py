@@ -382,6 +382,160 @@ def test_error_feedback_off_keeps_no_state():
     assert float(np.abs(decompress(cu2)).max()) == 0.0
 
 
+# ---------------------------------------------------------------------------
+# The device encoder against the numpy formula it replaced
+# ---------------------------------------------------------------------------
+
+def _numpy_encode(x, codec):
+    """The host quantizer the device encoder replaced, as the oracle:
+    codes of the ``n`` elements, scales, and the residual of the padded
+    blocks ``x - decompress``."""
+    n = x.size
+    nb = -(-n // QBLOCK)
+    padded = np.zeros(nb * QBLOCK, np.float32)
+    padded[:n] = x
+    blocks = padded.reshape(nb, QBLOCK)
+    if codec == "fp16":
+        data = blocks.astype(np.float16)
+        return data.reshape(-1)[:n], None, blocks - data.astype(np.float32)
+    absmax = np.max(np.abs(blocks), axis=1)
+    scales = (absmax / 127.0).astype(np.float32)
+    safe = np.where(scales > 0.0, scales, np.float32(1.0))
+    q = np.clip(np.rint(blocks / safe[:, None]), -127, 127).astype(np.int8)
+    q[scales == 0.0] = 0
+    deq = q.astype(np.float32) * scales[:, None]
+    return q.reshape(-1)[:n], scales, blocks - deq
+
+
+def _seeded_round(n, seed, zero_block=False):
+    """Global and local weights (two leaves) and a residual of padded
+    blocks, zero past ``n``."""
+    rng = np.random.default_rng(seed)
+    g = (rng.standard_normal(n) * 0.1).astype(np.float32)
+    local = (g + rng.standard_normal(n) * 0.01).astype(np.float32)
+    nb = -(-n // QBLOCK)
+    resid = np.zeros(nb * QBLOCK, np.float32)
+    resid[:n] = rng.standard_normal(n) * 1e-3
+    if zero_block:
+        local[QBLOCK:2 * QBLOCK] = g[QBLOCK:2 * QBLOCK]
+        resid[QBLOCK:2 * QBLOCK] = 0.0
+    cut = n // 3
+    tree = lambda v: {"a": jnp.asarray(v[:cut]), "b": jnp.asarray(v[cut:])}
+    return tree(g), tree(local), resid.reshape(nb, QBLOCK), local - g
+
+
+ENCODER_CASES = {
+    "n_below_one_block": (QBLOCK - 1000, False),
+    "partial_last_block": (QBLOCK + 17, False),
+    "all_zero_block": (3 * QBLOCK + 5, True),
+    "several_blocks": (6 * QBLOCK, False),
+}
+
+
+@pytest.mark.parametrize("codec", ["int8", "fp16"])
+@pytest.mark.parametrize("case", sorted(ENCODER_CASES))
+def test_device_encoder_matches_numpy_oracle(case, codec):
+    """Codes and scales equal the host formula's, bar one code at a
+    rounding tie in at most 1e-5 of the elements; the residual within
+    one ulp of the encoded value (the device may fuse ``x - q * s``
+    into one multiply-add, which rounds once where numpy rounds twice)."""
+    from repro.federated.compression import _encode_update
+
+    n, zero_block = ENCODER_CASES[case]
+    g, local, resid, delta = _seeded_round(n, seed=len(case), zero_block=zero_block)
+    x = resid.copy()
+    x.reshape(-1)[:n] += delta
+    want_data, want_scales, want_resid = _numpy_encode(x.reshape(-1)[:n], codec)
+
+    data, scales, new_resid = _encode_update(g, local, jnp.asarray(resid), codec=codec)
+    data, new_resid = np.asarray(data), np.asarray(new_resid)
+    assert data.dtype == want_data.dtype and data.shape == (n,)
+    if codec == "int8":
+        np.testing.assert_array_equal(np.asarray(scales), want_scales)
+        off = np.abs(data.astype(np.int16) - want_data.astype(np.int16))
+        assert off.max() <= 1 and np.count_nonzero(off) <= 1e-5 * n
+        if zero_block:
+            assert want_scales[1] == 0.0
+            np.testing.assert_array_equal(data[QBLOCK:2 * QBLOCK], 0)
+    else:
+        assert scales is None
+        np.testing.assert_array_equal(data, want_data)
+    assert new_resid.shape == resid.shape
+    ulp = np.spacing(np.abs(x)).astype(np.float32)
+    assert np.all(np.abs(new_resid - want_resid) <= ulp)
+    np.testing.assert_array_equal(new_resid.reshape(-1)[n:], 0.0)
+
+
+@pytest.mark.parametrize("codec", ["int8", "fp16"])
+def test_device_error_feedback_ships_the_residual_next_round(codec):
+    """Round 2's zero delta ships exactly what round 1's codec dropped."""
+    g, local, _, delta = _seeded_round(2 * QBLOCK + 300, seed=11)
+    comp = ClientCompressor(CompressionSpec(codec))
+    cu1 = comp.encode(g, local)
+    dropped = delta - decompress(cu1)
+    np.testing.assert_allclose(
+        np.asarray(comp._residual).reshape(-1)[: delta.size], dropped,
+        atol=float(np.spacing(np.abs(delta).max())),
+    )
+    cu2 = comp.encode(g, g)
+    shipped = decompress(cu2)
+    # The second encode quantizes the residual alone, so it ships it to
+    # within its own codec error: half a step of the block's int8 scale,
+    # or of fp16 (relative 2^-11, or 2^-25 among its subnormals).
+    if codec == "int8":
+        np.testing.assert_allclose(shipped, dropped, rtol=0, atol=float(cu2.scales.max()) / 2)
+    else:
+        np.testing.assert_allclose(shipped, dropped, rtol=2.0**-11, atol=2.0**-25)
+    assert np.abs(shipped).max() > 0.0
+
+
+@pytest.mark.parametrize("error_feedback", [True, False])
+def test_device_residual_is_a_device_array_or_none(error_feedback):
+    import jax
+
+    g, local, _, _ = _seeded_round(QBLOCK + 17, seed=12)
+    comp = ClientCompressor(CompressionSpec("int8", error_feedback=error_feedback))
+    for _ in range(2):
+        comp.encode(g, local)
+        if error_feedback:
+            assert isinstance(comp._residual, jax.Array)
+            assert comp._residual.shape == (2, QBLOCK)
+        else:
+            assert comp._residual is None
+    comp.reset()
+    assert comp._residual is None
+
+
+def test_device_encoder_compiles_once_per_shape():
+    """A fresh compressor's first encode (zero residual) and its second
+    run one compiled program, counted by the jit's cache."""
+    from repro.federated.compression import _encode_update
+
+    g, local, _, _ = _seeded_round(2 * QBLOCK + 9, seed=13)
+    comp = ClientCompressor(CompressionSpec("int8"))
+    comp.encode(g, local)
+    compiled = _encode_update._cache_size()
+    comp.encode(g, local)
+    ClientCompressor(CompressionSpec("int8")).encode(g, local)
+    assert _encode_update._cache_size() == compiled
+
+
+def test_device_encode_counts_its_copies():
+    """Inside fl.encode the host receives the codes and scales alone."""
+    from repro import spans
+
+    n = 2 * QBLOCK + 9
+    g, local, _, _ = _seeded_round(n, seed=14)
+    comp = ClientCompressor(CompressionSpec("int8"))
+    log = spans.SpanLog()
+    with spans.collect(log):
+        comp.encode(g, local)
+    assert [s.name for s in log.spans] == ["fl.encode"]
+    assert log.spans[0].nbytes == 4 * n
+    assert log.counters["d2h_bytes"] == n + 4 * 3
+    assert log.counters["encode_device"] == 1
+
+
 def _convergence_loss(compression, n_rounds=12):
     clients = make_paced_clients(
         {"c0": 0.0, "c1": 0.0}, n_examples=(24, 24), seed=7
@@ -460,11 +614,11 @@ def test_sim_vs_live_parity_with_compression(codec):
     both drivers encode the same deterministic codecs against the same
     bases) and identical trace signatures."""
     clients = make_paced_clients({"c0": 0.0, "c1": 0.0})
-    from test_transport import chain_replies
-    chain_replies(clients[0], clients[1])
+    from test_transport import chain_on_arrival
     driver = (Experiment().aggregation(compression=codec)
               .transport(reply_timeout_s=30.0)
               .serve(clients, init_params()))
+    chain_on_arrival(driver, *clients)
     assert isinstance(driver, LiveRoundDriver)
     assert driver.compression == parse_compression(codec)
     with driver:

@@ -15,7 +15,7 @@ from repro.core import Experiment
 from repro.optim import make_optimizer
 from repro.federated import SocketTransport
 from repro.federated.transport import _ConnState
-from test_transport import ArraySilo, PacedClient, _wire, chain_replies, trace_signature
+from test_transport import ArraySilo, PacedClient, _wire, chain_on_arrival, trace_signature
 
 # ---------------------------------------------------------------------------
 # The recorder
@@ -169,7 +169,6 @@ def _clients(codec):
         y = rng.standard_normal((n,)).astype(np.float32)
         clients.append(PacedClient(cid, ArraySilo(cid, x, y), _loss, make_optimizer("sgdm", 1e-2),
                                    batch_size=8, compression=codec))
-    chain_replies(clients[0], clients[1])   # c0's reply always lands first
     return clients
 
 
@@ -177,7 +176,9 @@ def _run(codec, n_rounds=2):
     exp = Experiment().transport(reply_timeout_s=60.0)
     if codec is not None:
         exp = exp.aggregation(compression=codec)
-    driver = exp.serve(_clients(codec), _params())
+    clients = _clients(codec)
+    driver = exp.serve(clients, _params())
+    chain_on_arrival(driver, *clients)   # c0's reply always lands first
     with driver:
         result = driver.run(n_rounds)
     return driver, result
@@ -247,10 +248,11 @@ def test_byte_counters_match_the_message_log(live):
         assert 2 * 8 <= c["recv_reads"] <= c["recv_bytes"]
         # H2D: each silo's two received weight sets, and the driver's
         # dense replies or the int8 payloads its fold moved.  D2H: the
-        # driver's two messages, and the silos' weights or encoder flats.
+        # driver's two messages, and the silos' weights or int8 codes.
         n = (64 + 1) * WIDTH
         assert c["h2d_bytes"] >= 2 * 2 * 4 * n + (2 * n if codec else 2 * 4 * n)
-        assert c["d2h_bytes"] >= 2 * 4 * n + (2 * 2 * 4 * n if codec else 2 * 4 * n)
+        assert c["d2h_bytes"] >= 2 * 4 * n + (2 * n if codec else 2 * 4 * n)
+        assert c.get("encode_device", 0) == (2 if codec else 0)
         assert c["step_calls"] == 3 + 5
         for name in ("d2h_s", "pack_s", "unpack_s", "h2d_s", "step_dispatch_s"):
             assert c[name] > 0, name

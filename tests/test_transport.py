@@ -35,7 +35,7 @@ from repro.federated import (
     ThreadWorkerPool,
 )
 from repro.federated.async_server import ArrivalSchedule, ClientArrival
-from repro.federated.transport import _ConnState, recv_frame, send_frame
+from repro.federated.transport import MSG_C_TRAIN, _ConnState, recv_frame, send_frame
 from repro.optim import make_optimizer
 
 
@@ -144,6 +144,28 @@ def chain_replies(first, second):
     sem = threading.Semaphore(0)
     first.release_sem = sem
     second.acquire_sem = sem
+
+
+def chain_on_arrival(driver, first, second):
+    """Like :func:`chain_replies`, with `first`'s token released when the
+    driver polls `first`'s c_msg_train off its transport rather than when
+    `first`'s train() returns: `second` trains only once that reply has
+    arrived, however long `first` takes to encode, serialize and send.
+    Call after ``serve()`` returns."""
+    sem = threading.Semaphore(0)
+    first.release_sem = None
+    second.acquire_sem = sem
+    poll = driver.transport.poll
+
+    def polled(timeout):
+        events = poll(timeout)
+        for ev in events:
+            if (ev.kind == "message" and ev.client_id == first.client_id
+                    and ev.header.get("kind") == MSG_C_TRAIN):
+                sem.release()
+        return events
+
+    driver.transport.poll = polled
 
 
 def trace_signature(trace):
