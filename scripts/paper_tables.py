@@ -1,6 +1,12 @@
-"""One benchmark function per paper table/figure. Each returns
+"""The paper's control-plane tables and figures, reproduced on the
+virtual-clock simulator: one function per table, each returning
 (name, us_per_call, derived) rows where `derived` is the table's headline
-metric, plus a human-readable report printed to stderr.
+metric beside the paper's value, plus a human-readable report on stderr.
+These are quality outputs ($, makespan, slowdowns); the wall time column
+is the simulator's own and no evidence of speed.
+
+Usage:
+  PYTHONPATH=src python scripts/paper_tables.py
 """
 from __future__ import annotations
 
@@ -277,3 +283,22 @@ def bench_poc_aws_gcp() -> List[Row]:
         ("s5_7_poc_on_demand_cost", us_od, f"{od.total_cost:.2f}_vs_3.28"),
         ("s5_7_poc_spot_savings_pct", us_spot, f"{savings:.1f}_vs_56.9"),
     ]
+
+
+def main() -> None:
+    """Print every table's rows as ``name,derived`` CSV on stdout."""
+    print("name,derived")
+    for bench in (
+        bench_pre_scheduling,       # Tables 3, 4
+        bench_initial_mapping,      # §5.4
+        bench_checkpoint_overhead,  # §5.5 / Fig. 2
+        bench_failure_til,          # Tables 5, 6
+        bench_failure_benchmarks,   # Tables 7, 8
+        bench_poc_aws_gcp,          # §5.7
+    ):
+        for name, _, derived in bench():
+            print(f"{name},{derived}")
+
+
+if __name__ == "__main__":
+    main()

@@ -20,10 +20,8 @@ and the shim-equivalence pin possible.
 
 Publication is synchronous and in-process: ``publish`` appends to the
 trace (when recording) and invokes subscribers immediately, so the bus
-adds only a dict lookup and a list append per event — the
-`benchmarks/control_plane_bench.py` harness pins this overhead at <5%
-of a deadline-bench round.  :data:`NULL_BUS` is the zero-cost sink for
-callers that want no tracing at all.
+adds only a dict lookup and a list append per event.  :data:`NULL_BUS`
+is the zero-cost sink for callers that want no tracing at all.
 """
 from __future__ import annotations
 
@@ -397,8 +395,7 @@ class EventBus:
 
 
 class NullBus(EventBus):
-    """A bus that drops everything: the zero-overhead baseline used by
-    `benchmarks/control_plane_bench.py` to pin the event-bus cost."""
+    """A bus that drops everything: tracing off, at no cost."""
 
     def __init__(self) -> None:
         super().__init__(record=False)

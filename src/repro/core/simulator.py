@@ -62,7 +62,7 @@ EscalationEvent = StragglerEscalated
 class SimulationConfig:
     """Deprecated shim — prefer `repro.core.control_plane.Experiment`.
 
-    Kept so existing callers/tests/benchmarks run unchanged; the fluent
+    Kept so existing callers and tests run unchanged; the fluent
     builder produces exactly this object (see docs/control_plane.md for
     the kwarg -> builder-method migration table).  Fields are validated
     at construction; app-dependent coherence (quorum vs cohort size) is

@@ -20,8 +20,8 @@ Dispatch hierarchy (backend-aware, detected once per engine):
           weighted multiply-add chain per leaf into a single pass over
           the inputs (a dot over the client axis), with no per-round
           Python loop and no ``(N, L)`` materialization.  For buffers
-          that are *already* stacked ``(N, L)`` (pod replica stacks,
-          benchmarks) the reduce is a single fp32-accumulated
+          that are *already* stacked ``(N, L)`` (pod replica stacks)
+          the reduce is a single fp32-accumulated
           ``jnp.einsum``.
 
 A chunked mode (`reduce_flat(..., chunk_elems=...)`) streams the reduce
@@ -1976,8 +1976,7 @@ def make_measured_aggreg_fn(
     `AggStats.last_folded_bytes` — the reduce runs over dequantized fp32
     regardless of what crossed the wire, so folded, not wire, bytes set
     the aggregation time);
-    ``gb_per_s`` the measured engine bandwidth (benchmarks/aggregation_bench
-    reports it per shape).  The time scales with each VM's instance
+    ``gb_per_s`` the engine's measured bandwidth.  The time scales with each VM's instance
     slowdown exactly like the paper's `aggreg_bl` baseline does.
     """
     if gb_per_s <= 0:

@@ -13,7 +13,7 @@ Dispatch hierarchy (hot paths never run the per-leaf Python loop):
       replica stack, lowered inside `pod_fedavg.fl_round_step`; wraps
       `agg_engine.fused_stacked_tree_reduce`.
   `fedavg` (below)                 — the pure-jnp per-leaf oracle, kept
-      ONLY as the correctness ground truth for tests and benchmarks.
+      ONLY as the correctness ground truth for tests.
 """
 from __future__ import annotations
 

@@ -104,16 +104,10 @@ from .agg_engine import (
     ResolvedSchema,
     StalenessPolicy,
     UpdateSchema,
-    as_update_schema,
     plan_for,
 )
 from .client import ClientResult
-from .compression import (
-    CompressedUpdate,
-    StructuredUpdate,
-    materialize_structured,
-    materialize_update,
-)
+from .compression import WireForm
 
 __all__ = [
     "ArrivalSchedule",
@@ -473,8 +467,7 @@ class FoldReport:
     # Counterfactual: wait for the last arrival, then do the SAME fold
     # work (last_arrival + busy_s).  With measured fold costs this is an
     # upper bound on the real sync FLServer's span — the barrier path
-    # runs the fused batch reduce, which beats N incremental folds; see
-    # benchmarks/async_round_bench.py for the measured-batch comparison.
+    # runs the fused batch reduce, which beats N incremental folds.
     # Under a deadline the counterfactual is the PR-2 barrier-on-count
     # timeline: wait for every deliverable message (including the ones the
     # deadline deferred), then fold them all.
@@ -535,6 +528,10 @@ class AsyncRoundEngine:
         RoundClosed — all on the round's virtual clock).  None creates a
         private recording bus; pass ``repro.core.events.NULL_BUS`` to
         disable tracing entirely.
+    form : the run's :class:`~repro.federated.compression.WireForm`:
+        rounds with a base fold a structured form's groups through the
+        per-group aggregator, and a late update parks as the form says.
+        None: dense frames.
     """
 
     def __init__(
@@ -548,7 +545,7 @@ class AsyncRoundEngine:
         carry_discount: float = 0.5,
         escalate_after: int = 2,
         bus: Optional[EventBus] = None,
-        schema: Union[None, UpdateSchema, Mapping[str, Any]] = None,
+        form: Optional[WireForm] = None,
         staleness_policy: Optional[StalenessPolicy] = None,
     ) -> None:
         if on_revocation not in ("rerequest", "exclude"):
@@ -564,10 +561,7 @@ class AsyncRoundEngine:
         self.carry_discount = carry_discount
         self.escalate_after = escalate_after
         self.bus = bus if bus is not None else EventBus()
-        # Structured updates: rounds with a base fold through the
-        # per-group StructuredStreamingAggregator under this schema.
-        self.schema = as_update_schema(schema)
-        self._resolved_schema: Optional[ResolvedSchema] = None
+        self.form = form if form is not None else WireForm()
         # Carried-over weight rule; None keeps the PR-3 age discount
         # (AgeDiscount(carry_discount) — bit-identical arithmetic).
         self.staleness_policy = staleness_policy
@@ -579,15 +573,6 @@ class AsyncRoundEngine:
         self.stragglers = StragglerTracker(escalate_after)
 
     # ------------------------------------------------------------------
-    def _resolve_schema(self, base_params: Any) -> Optional[ResolvedSchema]:
-        if self.schema is None or base_params is None:
-            return None
-        plan = plan_for(base_params)
-        if (self._resolved_schema is None
-                or self._resolved_schema.plan.signature != plan.signature):
-            self._resolved_schema = self.schema.resolve(base_params)
-        return self._resolved_schema
-
     def _park_delta_norm(
         self, park_params: Any, base_params: Any
     ) -> Optional[float]:
@@ -604,8 +589,8 @@ class AsyncRoundEngine:
     def _distance_to_base(self, params: Any, base_params: Any) -> float:
         """L2 distance between an update (full tree or per-group raw
         vectors) and the given global weights."""
-        if isinstance(params, Mapping) and self.schema is not None:
-            resolved = self._resolve_schema(base_params)
+        if isinstance(params, Mapping) and self.form.schema is not None:
+            resolved: Optional[ResolvedSchema] = self.form.resolve(base_params)
             if resolved is not None and all(
                 k in dict(resolved.groups) for k in params
             ):
@@ -664,13 +649,12 @@ class AsyncRoundEngine:
         drained first — they are already sitting on the server.
 
         ``base_params`` (the round's global weights) switches the fold to
-        the aggregator's flat/delta mode — required when results carry
-        :class:`~repro.federated.compression.CompressedUpdate` payloads.
-        A compressed update that misses the deadline is *materialized*
-        (dequantized against this round's base) before it is parked: the
-        delta is only meaningful against its origin round's base, which
-        the next round no longer has, so the carry buffer always holds
-        dense, base-independent parameters.
+        the aggregator's flat/delta mode — required when the engine's
+        form carries deltas or groups.  An update that misses the
+        deadline parks as ``form.park`` makes it: a delta is only
+        meaningful against its origin round's base, which the next round
+        no longer has, so the carry buffer always holds base-independent
+        weights.
 
         ``emit_partial=True`` (hierarchy: this engine is a regional
         aggregator) finishes the round as a
@@ -723,7 +707,7 @@ class AsyncRoundEngine:
         agg = self.agg_engine.streaming(
             base=base_params,
             base_round=round_idx if base_params is not None else None,
-            schema=self.schema if base_params is not None else None,
+            schema=self.form.schema if base_params is not None else None,
         )
         events: List[FoldEvent] = []
         excluded: List[str] = []
@@ -794,19 +778,9 @@ class AsyncRoundEngine:
                 # Missed the (quorum-extended) deadline: park the update
                 # for the next round's discounted average and advance the
                 # silo's miss streak toward §4.4 escalation.
-                park_params = res.params
-                if isinstance(park_params, CompressedUpdate):
-                    # A compressed delta is pinned to THIS round's base;
-                    # the next round's aggregator has a different one.
-                    # Materialize now, while the origin base is on hand.
-                    park_params = materialize_update(base_params, park_params)
-                elif isinstance(park_params, StructuredUpdate):
-                    # Same base-pinning applies per group: materialize to
-                    # {group: raw fp32 values} before parking.
-                    park_params = materialize_structured(
-                        base_params, park_params,
-                        self._resolve_schema(base_params),
-                    )
+                # A delta is pinned to THIS round's base; the next
+                # round's aggregator has a different one.
+                park_params = self.form.park(base_params, res.params)
                 self.carry.defer(
                     CarryEntry(cid, park_params, float(res.n_samples),
                                origin_round=round_idx,
@@ -1013,25 +987,16 @@ class AsyncFLServer(FLServer):
         staleness_policy: Optional[StalenessPolicy] = None,
         **kwargs: Any,
     ) -> None:
-        from .compression import ClientCompressor, parse_compression
-
         super().__init__(clients, initial_params, **kwargs)
         self.schedule = schedule if schedule is not None else InstantSchedule()
-        # `compression` turns on the compressed wire path: each client's
-        # update is encoded as a quantized/sparsified delta against the
-        # round's global weights (with per-client error feedback) and
-        # folded via the aggregator's fused dequantize-and-fold path —
-        # the virtual-clock twin of the live transport's worker-side
-        # encoding, producing bit-identical updates for parity.
-        self._compression = parse_compression(compression)
-        self._compressors: Dict[str, ClientCompressor] = {}
-        # `schema` turns on structured updates: each client's update is
-        # re-encoded as a StructuredUpdate carrying only the schema's
-        # named groups (per-group error feedback when compression is
-        # also on), folded through the per-group masked aggregator.
-        self._schema = as_update_schema(schema)
+        # `compression` and `schema` set the run's wire form: each
+        # client's update is encoded as the form says (a delta against
+        # the round's global weights, per-client error feedback, named
+        # groups) — the virtual-clock twin of the live transport's
+        # worker-side encoding, producing bit-identical updates.
+        self._form = WireForm(compression, schema)
         self._staleness_policy = staleness_policy
-        self._struct_encoders: Dict[str, Any] = {}
+        self._encoders: Dict[str, Any] = {}
         self._round_engine = AsyncRoundEngine(
             self.agg_engine,
             on_revocation=on_revocation,
@@ -1042,7 +1007,7 @@ class AsyncFLServer(FLServer):
             carry_discount=carry_discount,
             escalate_after=escalate_after,
             bus=self.bus,
-            schema=self._schema,
+            form=self._form,
             staleness_policy=staleness_policy,
         )
         self.on_straggler = on_straggler
@@ -1053,62 +1018,30 @@ class AsyncFLServer(FLServer):
         """Late updates parked for the next round (empty without deadlines)."""
         return self._round_engine.carry
 
-    def _compressor_for(self, client_id: str) -> Any:
-        """The client's own compressor when it has one (client-owned
-        error-feedback residual), else a server-held per-client one."""
-        from .compression import ClientCompressor
-
-        for c in self.clients:
-            if str(c.client_id) == client_id:
-                owned = getattr(c, "compressor", None)
-                if owned is not None:
-                    return owned
-                break
-        return self._compressors.setdefault(
-            client_id, ClientCompressor(self._compression)
-        )
-
-    def _structured_encoder_for(self, client_id: str) -> Any:
-        """Per-client structured encoder (holds per-group error feedback)."""
-        from .compression import StructuredCompressor
-
-        enc = self._struct_encoders.get(client_id)
-        if enc is None:
-            enc = StructuredCompressor(self._schema, self._compression)
-            self._struct_encoders[client_id] = enc
-        return enc
+    def _encoded(
+        self, round_idx: int, results: Sequence[ClientResult]
+    ) -> List[ClientResult]:
+        """The round's results as the wire form carries them, encoded
+        against ``self.params`` — still the dispatched global weights
+        here (updated only after the fold), so both the delta base for
+        encoding and the aggregation base for folding.  Each client keeps
+        one encoder (its error-feedback state) across rounds."""
+        if not self._form.against_base:
+            return list(results)
+        by_id = {str(c.client_id): c for c in self.clients}
+        out = []
+        for r in results:
+            enc = self._encoders.get(r.client_id)
+            if enc is None:
+                enc = self._encoders[r.client_id] = self._form.encoder(by_id.get(r.client_id))
+            out.append(dataclasses.replace(
+                r, params=self._form.encode(enc, self.params, r.params, round_idx)
+            ))
+        return out
 
     def _fold_phase(self, round_idx: int, results: Sequence[ClientResult]) -> FoldReport:
-        base = None
-        if self._schema is not None:
-            # Structured rounds: clients ship only the schema's named
-            # groups.  self.params is still the dispatched global weights
-            # (updated only after the fold), so it is both the encoding
-            # base and the aggregation base.
-            base = self.params
-            results = [
-                dataclasses.replace(
-                    r,
-                    params=self._structured_encoder_for(r.client_id).encode(
-                        base, r.params, base_round=round_idx
-                    ),
-                )
-                for r in results
-            ]
-        elif self._compression is not None:
-            # self.params is still the round's dispatched global weights
-            # here (updated only after the fold), so it is both the delta
-            # base for encoding and the aggregation base for folding.
-            base = self.params
-            results = [
-                dataclasses.replace(
-                    r,
-                    params=self._compressor_for(r.client_id).encode(
-                        base, r.params, base_round=round_idx
-                    ),
-                )
-                for r in results
-            ]
+        base = self.params if self._form.against_base else None
+        results = self._encoded(round_idx, results)
         report = self._round_engine.fold_round(
             round_idx, results, self.schedule, base_params=base
         )

@@ -32,6 +32,8 @@ import numpy as np
 
 from repro import spans
 
+from .compression import WireForm
+
 
 @dataclasses.dataclass
 class ClientResult:
@@ -83,16 +85,9 @@ class FLClient:
         self._opt_state = None
         # Client-owned compression state: the error-feedback residual
         # stays with the silo (not the transport invocation), so worker
-        # restarts over the same client object keep it.  The transport
-        # worker and AsyncFLServer both prefer this compressor when the
-        # wire path is compressed.
-        self.compressor = None
-        if compression is not None:
-            from .compression import ClientCompressor, parse_compression
-
-            spec = parse_compression(compression)
-            if spec is not None:
-                self.compressor = ClientCompressor(spec)
+        # restarts over the same client object keep it.  A compressed
+        # run's WireForm prefers this encoder to one of its own.
+        self.compressor = WireForm(compression).encoder()
 
         # The step's last (weights, optimizer state), which the next call
         # may donate; batch signatures whose programs are compiled.
@@ -170,16 +165,6 @@ class FLClient:
                 n_samples=n_first_epoch,
                 train_time_s=time.monotonic() - t0,
             )
-
-    def encode_update(self, global_params: Any, local_params: Any) -> Any:
-        """Compress this round's update with the client-owned
-        error-feedback buffer (requires ``compression=`` at init)."""
-        if self.compressor is None:
-            raise ValueError(
-                f"client {self.client_id!r} has no compressor; pass "
-                "compression= when constructing the FLClient"
-            )
-        return self.compressor.encode(global_params, local_params)
 
     # -- evaluation phase -----------------------------------------------------
     def evaluate(self, aggregated_params: Any) -> EvalResult:

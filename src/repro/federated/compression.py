@@ -27,6 +27,11 @@ The server side never materializes a dense fp32 update: the
 :class:`~repro.federated.agg_engine.StreamingAggregator` folds
 :class:`CompressedUpdate` payloads straight into its fp32 accumulator via
 the fused Pallas dequantize-and-fold kernel (``dequant_fold``).
+
+:class:`WireForm` decides, once per run, which of these forms a
+``c_msg_train`` takes (dense, compressed, or structured per-group) and
+answers for it everywhere an update is encoded, sent, decoded, folded,
+parked or accounted for.
 """
 from __future__ import annotations
 
@@ -41,7 +46,12 @@ import msgpack
 import numpy as np
 
 from repro import spans
-from repro.checkpoint.serializer import BytesLike, DeserializationError
+from repro.checkpoint.serializer import (
+    BytesLike,
+    DeserializationError,
+    deserialize_pytree,
+    serialize_pytree,
+)
 
 # One quantization block per Pallas grid tile of the fused
 # dequantize-and-fold kernel (kernels/fedavg_reduce.BLOCK), so the (B,)
@@ -429,15 +439,18 @@ def _decode_update_obj(obj: Dict[str, Any]) -> CompressedUpdate:
     )
 
 
-def compressed_wire_bytes(total_elems: int, spec: CompressionSpec) -> int:
-    """Serialized c_msg_train size for a model of ``total_elems`` weights.
+def compressed_wire_bytes(
+    total_elems: int, spec: CompressionSpec, base_round: Optional[int] = None
+) -> int:
+    """Serialized c_msg_train size for a model of ``total_elems`` weights
+    (tagged with ``base_round`` when given).
 
     Compressed frame sizes are data-independent given the element count
     (fixed-width codes plus msgpack framing), so message accounting can
     report exact wire bytes without compressing real data.
     """
     zeros = np.zeros(total_elems, dtype=np.float32)
-    return len(serialize_update(compress(zeros, spec)))
+    return len(serialize_update(compress(zeros, spec, base_round=base_round)))
 
 
 # ---------------------------------------------------------------------------
@@ -710,9 +723,10 @@ class StructuredCompressor:
     Without a codec each group ships its raw fp32 *values* (already a
     huge win when the schema selects a small group like LoRA adapters);
     with a :class:`CompressionSpec` each group's *delta* against the
-    round base is compressed independently, with an independent
-    error-feedback residual per group (a group the client skips a round
-    keeps its residual — nothing is dropped).
+    round base is encoded by its own :class:`ClientCompressor` over that
+    group's leaves, so ``int8``/``fp16`` groups run the one device
+    program and keep their residuals on the device (a group the client
+    skips a round keeps its residual — nothing is dropped).
 
     The schema is resolved lazily against the first round's global
     weights and the resolution cached by plan signature, so repeated
@@ -720,23 +734,10 @@ class StructuredCompressor:
     """
 
     def __init__(self, schema: Any, spec: Union[None, str, CompressionSpec] = None) -> None:
-        from repro.federated.agg_engine import as_update_schema
-
-        self.schema = as_update_schema(schema)
-        if self.schema is None:
+        self.form = WireForm(spec, schema)
+        if self.form.schema is None:
             raise ValueError("StructuredCompressor needs a schema")
-        self.spec = parse_compression(spec)
-        self._resolved: Any = None
-        self._residuals: Dict[str, np.ndarray] = {}
-
-    def _resolve(self, params: Any) -> Any:
-        from repro.federated.agg_engine import plan_for
-
-        plan = plan_for(params)
-        if self._resolved is None or self._resolved.plan.signature != plan.signature:
-            assert self.schema is not None
-            self._resolved = self.schema.resolve(params)
-        return self._resolved
+        self._groups: Dict[str, ClientCompressor] = {}
 
     def encode(
         self,
@@ -745,27 +746,174 @@ class StructuredCompressor:
         base_round: Optional[int] = None,
     ) -> StructuredUpdate:
         """Encode the groups of this round's update (all schema groups)."""
-        resolved = self._resolve(global_params)
+        resolved = self.form.resolve(global_params)
+        spec = self.form.spec
+        if spec is None:
+            return StructuredUpdate(
+                groups=tuple(
+                    (name, np.asarray(gp.flatten(local_params), dtype=np.float32))
+                    for name, gp in resolved.groups
+                ),
+                schema_signature=resolved.signature,
+            )
+        glob, loc = jax.tree.leaves(global_params), jax.tree.leaves(local_params)
         groups: List[Tuple[str, GroupPayload]] = []
         for name, gp in resolved.groups:
-            p = np.asarray(gp.flatten(local_params), dtype=np.float32)
-            if self.spec is None:
-                groups.append((name, p))
-                continue
-            g = np.asarray(gp.flatten(global_params), dtype=np.float32)
-            delta = p - g
-            residual = self._residuals.get(name)
-            if self.spec.error_feedback and residual is not None:
-                delta = delta + residual
-            update = compress(delta, self.spec, base_round=base_round)
-            if self.spec.error_feedback:
-                self._residuals[name] = delta - decompress(update)
-            groups.append((name, update))
+            comp = self._groups.setdefault(name, ClientCompressor(spec))
+            groups.append((name, comp.encode(
+                [glob[i] for i in gp.leaf_indices],
+                [loc[i] for i in gp.leaf_indices],
+                base_round=base_round,
+            )))
         return StructuredUpdate(
             groups=tuple(groups),
             schema_signature=resolved.signature,
-            base_round=base_round if self.spec is not None else None,
+            base_round=base_round,
         )
 
     def reset(self) -> None:
-        self._residuals = {}
+        self._groups = {}
+
+
+# ---------------------------------------------------------------------------
+# The run's wire form: the one place that decides an update's encoding
+# ---------------------------------------------------------------------------
+
+class WireForm:
+    """How one run's ``c_msg_train`` carries a client's update.
+
+    Decided once from the run's ``(compression, schema)`` pair: a dense
+    fp32 pytree frame (neither), one compressed delta against the
+    round's global weights (``compression``), or the schema's named
+    groups (``schema``; each group a compressed delta when
+    ``compression`` is set too, else its raw fp32 values).  The worker
+    that encodes and sends a reply, the driver that decodes it, the
+    engine that folds or parks it and the message accounting all ask
+    this object instead of branching on the pair themselves.
+    """
+
+    def __init__(
+        self,
+        compression: Union[None, str, CompressionSpec] = None,
+        schema: Any = None,
+    ) -> None:
+        from repro.federated.agg_engine import as_update_schema
+
+        self.spec = parse_compression(compression)
+        self.schema = as_update_schema(schema)
+        self._resolved: Any = None
+
+    @property
+    def against_base(self) -> bool:
+        """Whether the fold runs against the round's global weights (a
+        delta or a group needs them; a dense frame is whole weights)."""
+        return self.spec is not None or self.schema is not None
+
+    @property
+    def codec(self) -> str:
+        """The ``RoundMessageLog.codec`` of the run's rounds."""
+        if self.schema is not None:
+            return "structured" if self.spec is None else f"structured:{self.spec.codec}"
+        return "none" if self.spec is None else self.spec.codec
+
+    def resolve(self, params: Any) -> Any:
+        """The schema bound to ``params``' structure (cached by plan
+        signature; ``None`` without a schema)."""
+        from repro.federated.agg_engine import plan_for
+
+        if self.schema is None:
+            return None
+        if self._resolved is None or self._resolved.plan.signature != plan_for(params).signature:
+            self._resolved = self.schema.resolve(params)
+        return self._resolved
+
+    def encoder(self, client: Any = None) -> Any:
+        """A fresh per-client encoder holding that client's error-feedback
+        residual (``None`` for dense frames).  A compressed form prefers
+        the client's own ``compressor`` (``FLClient(compression=...)``),
+        so its residual survives worker restarts over the same client."""
+        if self.schema is not None:
+            return StructuredCompressor(self.schema, self.spec)
+        if self.spec is None:
+            return None
+        owned = getattr(client, "compressor", None)
+        return owned if owned is not None else ClientCompressor(self.spec)
+
+    def encode(
+        self, encoder: Any, global_params: Any, local_params: Any, round_idx: int
+    ) -> Any:
+        """The update as the server folds it: a delta or groups tagged
+        with ``round_idx``, the base they were taken against (dense: the
+        local weights themselves)."""
+        if encoder is None:
+            return local_params
+        return encoder.encode(global_params, local_params, base_round=round_idx)
+
+    def reply(
+        self, encoder: Any, global_params: Any, local_params: Any, round_idx: int
+    ) -> Tuple[Dict[str, Any], bytes]:
+        """A worker's ``c_msg_train``: the header fields this form adds
+        (the sender's byte accounting) and the serialized body."""
+        from repro.federated.agg_engine import plan_for
+
+        update = self.encode(encoder, global_params, local_params, round_idx)
+        if self.schema is not None:
+            # Dense equivalent = the FULL model's fp32 bytes: the saving
+            # reported is "groups instead of the whole pytree".
+            return {
+                "structured": 1,
+                "dense_bytes": int(plan_for(global_params).total_elems * 4),
+                "group_bytes": {str(k): int(v) for k, v in update.group_wire_bytes().items()},
+                "group_dense": {str(k): int(v) for k, v in update.group_dense_bytes().items()},
+            }, serialize_structured(update)
+        if self.spec is not None:
+            return {
+                "codec": update.codec, "dense_bytes": int(update.dense_bytes),
+            }, serialize_update(update)
+        return {}, serialize_pytree(local_params)
+
+    def decode(self, payload: BytesLike, template: Any) -> Any:
+        """The driver's decode of a ``c_msg_train`` body (dense frames
+        into ``template``'s structure).  Raises
+        :class:`~repro.checkpoint.serializer.DeserializationError` on a
+        corrupt frame in every form."""
+        if self.schema is not None:
+            return deserialize_structured(payload)
+        if self.spec is not None:
+            return deserialize_update(payload)
+        return deserialize_pytree(payload, template)
+
+    def park(self, base: Any, update: Any) -> Any:
+        """What a late update parks as: base-independent weights (a
+        delta, per group, materialized while its origin base is on hand;
+        a dense update as it is)."""
+        if self.schema is not None:
+            return materialize_structured(base, update, self.resolve(base))
+        if self.spec is not None:
+            return materialize_update(base, update)
+        return update
+
+    def message_sizes(self, params: Any, dense_frame_bytes: int) -> Dict[str, Any]:
+        """The ``c_msg_train`` fields of a
+        :class:`~repro.federated.messages.RoundMessageLog` for a model
+        shaped like ``params``, whose dense frame is ``dense_frame_bytes``
+        long.  Compressed and structured frames are fixed-width given the
+        element counts, so the sizes are exact; each is tagged as every
+        round's reply is."""
+        from repro.federated.agg_engine import plan_for
+
+        sizes: Dict[str, Any] = {"codec": self.codec, "c_msg_train_bytes": dense_frame_bytes}
+        if not self.against_base:
+            return sizes
+        sizes["c_msg_train_dense_bytes"] = plan_for(params).total_elems * 4
+        if self.schema is None:
+            assert self.spec is not None
+            sizes["c_msg_train_bytes"] = compressed_wire_bytes(
+                plan_for(params).total_elems, self.spec, base_round=0
+            )
+            return sizes
+        update = self.encode(self.encoder(), params, params, 0)
+        sizes["c_msg_train_bytes"] = len(serialize_structured(update))
+        sizes["group_wire_bytes"] = update.group_wire_bytes()
+        sizes["group_dense_bytes"] = update.group_dense_bytes()
+        return sizes

@@ -68,7 +68,6 @@ from .agg_engine import (
     PartialSum,
     StreamingAggregator,
     StructuredPartialSum,
-    as_update_schema,
 )
 from .async_server import (
     ArrivalSchedule,
@@ -79,6 +78,7 @@ from .async_server import (
     RoundDeadline,
 )
 from .client import ClientResult
+from .compression import WireForm
 
 __all__ = [
     "CohortSampler",
@@ -379,7 +379,10 @@ class HierarchyCoordinator:
     sequential :meth:`~repro.federated.agg_engine.StreamingAggregator
     .fold_partial` calls, or a :class:`ShardedPartialFolder` psum when
     ``sharded=True``.  Publishes :class:`~repro.core.events.RegionClosed`
-    and :class:`~repro.core.events.PartialFolded` on ``bus``.
+    and :class:`~repro.core.events.PartialFolded` on ``bus``.  ``form``
+    is the run's :class:`~repro.federated.compression.WireForm`: with a
+    schema every regional engine folds its named groups and exports a
+    :class:`StructuredPartialSum`, which the parent folds per group.
     """
 
     def __init__(
@@ -397,16 +400,14 @@ class HierarchyCoordinator:
         sharded: bool = False,
         mesh: Optional[Any] = None,
         bus: Optional[EventBus] = None,
-        schema: Optional[Any] = None,
+        form: Optional[WireForm] = None,
         staleness_policy: Optional[Any] = None,
     ) -> None:
         if not region_map:
             raise ValueError("a hierarchy needs at least one region")
         self.agg_engine = agg_engine if agg_engine is not None else AggregationEngine()
-        # Structured updates: every regional engine folds the schema's
-        # named groups and exports a StructuredPartialSum; the parent
-        # folds those per group under the same schema.
-        self.schema = as_update_schema(schema)
+        form = form if form is not None else WireForm()
+        self.schema = form.schema
         self.sampler = sampler
         self.bus = bus if bus is not None else EventBus()
         self.sharded = sharded
@@ -426,7 +427,7 @@ class HierarchyCoordinator:
                 carry_discount=carry_discount,
                 escalate_after=escalate_after,
                 bus=NULL_BUS,
-                schema=self.schema,
+                form=form,
                 staleness_policy=staleness_policy,
             )
             region = RegionalAggregator(str(rid), cids, engine)
@@ -742,7 +743,7 @@ class HierarchicalFLServer(AsyncFLServer):
             sharded=sharded,
             mesh=mesh,
             bus=self.bus,
-            schema=self._schema,
+            form=self._form,
             staleness_policy=self._staleness_policy,
         )
 
@@ -772,30 +773,10 @@ class HierarchicalFLServer(AsyncFLServer):
     ) -> FoldReport:
         # The hierarchy always folds in flat/delta mode (partial sums
         # compose only against a shared base), so the round's dispatched
-        # globals are the base whether or not the wire is compressed.
-        base = self.params
-        if self._schema is not None:
-            results = [
-                dataclasses.replace(
-                    r,
-                    params=self._structured_encoder_for(r.client_id).encode(
-                        base, r.params, base_round=round_idx
-                    ),
-                )
-                for r in results
-            ]
-        elif self._compression is not None:
-            results = [
-                dataclasses.replace(
-                    r,
-                    params=self._compressor_for(r.client_id).encode(
-                        base, r.params, base_round=round_idx
-                    ),
-                )
-                for r in results
-            ]
+        # globals are the base whatever the wire form.
         report = self.coordinator.fold_round(
-            round_idx, results, self.schedule, base_params=base
+            round_idx, self._encoded(round_idx, results), self.schedule,
+            base_params=self.params,
         )
         self.fold_reports.append(report)
         if self.on_straggler is not None:

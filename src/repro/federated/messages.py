@@ -16,15 +16,13 @@ can state the achieved compression ratio.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, TYPE_CHECKING, Union
+from typing import Any, Dict, Optional, Union
 
 import msgpack
 
 from repro.checkpoint.serializer import pytree_num_bytes, serialize_pytree
 from repro.core.application_model import MessageSizes
-
-if TYPE_CHECKING:
-    from repro.federated.compression import CompressionSpec
+from repro.federated.compression import CompressionSpec, WireForm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,7 +72,7 @@ def serialize_metrics(metrics: Dict[str, float]) -> bytes:
 def measure_messages(
     params: Any,
     metrics_example: Dict[str, float],
-    compression: Union[None, str, "CompressionSpec"] = None,
+    compression: Union[None, str, CompressionSpec] = None,
     schema: Any = None,
 ) -> RoundMessageLog:
     """Measure real serialized sizes for one round's message set.
@@ -96,48 +94,11 @@ def measure_messages(
     (e.g. the >= 50x of adapter-only federated LoRA)."""
     weight_bytes = len(serialize_pytree(params))
     metric_bytes = len(serialize_metrics(metrics_example))
-    c_train_bytes = weight_bytes
-    codec = "none"
-    dense: Optional[int] = None
-    group_wire: Optional[Dict[str, int]] = None
-    group_dense: Optional[Dict[str, int]] = None
-    if schema is not None:
-        from repro.federated.agg_engine import plan_for
-        from repro.federated.compression import (
-            StructuredCompressor,
-            serialize_structured,
-        )
-
-        comp = StructuredCompressor(schema, compression)
-        update = comp.encode(params, params, base_round=0)
-        c_train_bytes = len(serialize_structured(update))
-        group_wire = update.group_wire_bytes()
-        group_dense = update.group_dense_bytes()
-        dense = plan_for(params).total_elems * 4
-        codec = ("structured" if comp.spec is None
-                 else f"structured:{comp.spec.codec}")
-    elif compression is not None:
-        from repro.federated.agg_engine import plan_for
-        from repro.federated.compression import (
-            compressed_wire_bytes,
-            parse_compression,
-        )
-
-        spec = parse_compression(compression)
-        if spec is not None:
-            plan = plan_for(params)
-            c_train_bytes = compressed_wire_bytes(plan.total_elems, spec)
-            codec = spec.codec
-            dense = plan.total_elems * 4
     return RoundMessageLog(
         s_msg_train_bytes=weight_bytes,
-        c_msg_train_bytes=c_train_bytes,
         s_msg_aggreg_bytes=weight_bytes,
         c_msg_test_bytes=metric_bytes,
-        codec=codec,
-        c_msg_train_dense_bytes=dense,
-        group_wire_bytes=group_wire,
-        group_dense_bytes=group_dense,
+        **WireForm(compression, schema).message_sizes(params, weight_bytes),
     )
 
 

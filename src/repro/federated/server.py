@@ -40,6 +40,7 @@ from repro.spans import Span
 from .agg_engine import AggregationEngine
 from .aggregation import aggregate_metrics
 from .client import ClientResult, EvalResult, FLClient
+from .compression import WireForm
 from .messages import RoundMessageLog, measure_messages
 
 if TYPE_CHECKING:
@@ -108,6 +109,7 @@ class FLServer:
         self.client_ckpts = client_ckpts or {}
         self.fault_hook = fault_hook
         self.measure_round_messages = measure_round_messages
+        self._form = WireForm()   # dense frames; AsyncFLServer sets its own
         self.start_round = 1
         # Server-side post-aggregation transform, called as
         # hook(round_idx, params) right after the fold; a non-None return
@@ -211,13 +213,10 @@ class FLServer:
 
         log = None
         if self.measure_round_messages:
-            # AsyncFLServer sets _compression when the wire path is
-            # compressed and _schema when updates are structured; the log
-            # then carries wire vs dense c_msg_train (and per-group maps).
+            # The run's wire form: compressed or structured c_msg_train
+            # logs carry wire vs dense bytes (and per-group maps).
             log = measure_messages(
-                self.params, metrics,
-                compression=getattr(self, "_compression", None),
-                schema=getattr(self, "_schema", None),
+                self.params, metrics, self._form.spec, self._form.schema
             )
         return RoundRecord(
             round_idx=round_idx,

@@ -113,6 +113,7 @@ from .async_server import (
 )
 from .chaos import DRIVER_KINDS, FaultPlan, corrupt_latest_checkpoint
 from .client import ClientResult
+from .compression import WireForm
 from .messages import RoundMessageLog, serialize_metrics, to_cost_model_sizes
 from .server import FLRunResult, RoundRecord
 
@@ -610,48 +611,22 @@ def run_client_worker(
     ``reconnect`` bounds connect retries with backoff + jitter (a single
     attempt when None).
 
-    ``compression`` (a :class:`~repro.federated.compression
-    .CompressionSpec` or codec string) switches the ``c_msg_train``
-    reply to a compressed delta against the received global weights,
-    with the error-feedback residual held in this worker.  The residual
-    dies with the worker: a restarted or replaced VM re-encodes from a
-    zero residual (slightly more compression error on its next update,
-    never a correctness problem).
-
-    ``schema`` (an :class:`~repro.federated.agg_engine.UpdateSchema` or
-    group mapping) switches the reply to a *structured* frame carrying
-    only the schema's named parameter groups — per-group compressed
-    deltas when ``compression`` is also set, raw fp32 group values
-    otherwise.  The header gains ``structured``/``group_bytes``/
-    ``group_dense`` so the driver's per-group byte accounting is
-    measured at the sender.
+    ``compression`` and ``schema`` are the run's
+    :class:`~repro.federated.compression.WireForm`, which encodes the
+    ``c_msg_train`` reply and adds its header fields (the sender's byte
+    accounting).  A compressed delta, tagged with the round it was
+    taken against, keeps its error-feedback residual in the client's
+    own ``compressor`` when it has one, else in this worker: a restarted
+    or replaced VM then re-encodes from a zero residual (slightly more
+    compression error on its next update, never a correctness problem).
     """
     sock = _connect_with_backoff(
         address, connect_timeout_s, reconnect, str(client.client_id)
     )
     if sock is None:
         return
-    compressor = None
-    struct_encoder = None
-    if schema is not None:
-        from .compression import StructuredCompressor
-
-        # Structured replies subsume plain compression: the encoder
-        # applies the codec (when any) per group, with per-group error
-        # feedback scoped to this worker.
-        struct_encoder = StructuredCompressor(schema, compression)
-    elif compression is not None:
-        from .compression import ClientCompressor, parse_compression
-
-        spec = parse_compression(compression)
-        if spec is not None:
-            # Prefer a client-owned compressor (FLClient(compression=...))
-            # so the error-feedback residual survives worker restarts
-            # over the same client object; else the buffer is scoped to
-            # this invocation (a fresh VM starts from zero residual).
-            compressor = getattr(client, "compressor", None)
-            if compressor is None:
-                compressor = ClientCompressor(spec)
+    form = WireForm(compression, schema)
+    encoder = form.encoder(client)
     send_lock = threading.Lock()
     jobs: "queue.Queue[Optional[Tuple[Dict[str, Any], BytesLike, spans.SpanLog]]]" = (
         queue.Queue()
@@ -685,38 +660,8 @@ def run_client_worker(
                 "n_samples": int(result.n_samples),
                 "train_time_s": float(result.train_time_s),
             }
-            if struct_encoder is not None:
-                from .agg_engine import plan_for
-                from .compression import serialize_structured
-
-                supdate = struct_encoder.encode(
-                    params, result.params, base_round=round_idx
-                )
-                header_out["structured"] = 1
-                # Dense equivalent = the FULL model's fp32 bytes:
-                # the savings being reported is "groups instead
-                # of the whole pytree", codec included.
-                header_out["dense_bytes"] = int(
-                    plan_for(params).total_elems * 4
-                )
-                header_out["group_bytes"] = {
-                    str(k): int(v)
-                    for k, v in supdate.group_wire_bytes().items()
-                }
-                header_out["group_dense"] = {
-                    str(k): int(v)
-                    for k, v in supdate.group_dense_bytes().items()
-                }
-                body = serialize_structured(supdate)
-            elif compressor is not None:
-                from .compression import serialize_update
-
-                update = compressor.encode(params, result.params)
-                header_out["codec"] = update.codec
-                header_out["dense_bytes"] = int(update.dense_bytes)
-                body = serialize_update(update)
-            else:
-                body = serialize_pytree(result.params)
+            fields, body = form.reply(encoder, params, result.params, round_idx)
+            header_out.update(fields)
             return header_out, body
         ev = client.evaluate(params)
         return {
@@ -1221,16 +1166,9 @@ class LiveRoundDriver:
         self.on_straggler = on_straggler
         self.cost_model = cost_model
         self.measure_round_messages = measure_round_messages
-        # The workers do the encoding (the pool must be built with the
-        # same spec); the driver's copy drives decode + the delta-mode
-        # fold + wire-vs-dense accounting in the round message logs.
-        from .agg_engine import as_update_schema
-        from .compression import parse_compression
-        self.compression = parse_compression(compression)
-        # Structured rounds: the pool's workers ship only the schema's
-        # named groups; the driver folds them through the per-group
-        # masked aggregator and logs per-group wire/dense bytes.
-        self.schema = as_update_schema(schema)
+        # The workers encode (the pool must be built with the same
+        # form); the driver's copy decodes, folds and accounts.
+        self.form = WireForm(compression, schema)
         self._on_revocation = on_revocation
         self._max_rerequests = max_rerequests
         self._engine = AsyncRoundEngine(
@@ -1242,7 +1180,7 @@ class LiveRoundDriver:
             carry_discount=carry_discount,
             escalate_after=escalate_after,
             bus=self.bus,
-            schema=self.schema,
+            form=self.form,
             staleness_policy=staleness_policy,
         )
         self.fold_reports: List[FoldReport] = []
@@ -1291,6 +1229,16 @@ class LiveRoundDriver:
     def cohort(self) -> List[str]:
         """Silos still in the run (terminal crashes drop out)."""
         return list(self._cohort)
+
+    @property
+    def compression(self) -> Optional[Any]:
+        """The run's codec (a ``CompressionSpec``; None: uncompressed)."""
+        return self.form.spec
+
+    @property
+    def schema(self) -> Optional[Any]:
+        """The run's ``UpdateSchema`` (None: unstructured updates)."""
+        return self.form.schema
 
     def _wall(self) -> float:
         return time.monotonic() - self._wall_t0
@@ -1394,11 +1342,7 @@ class LiveRoundDriver:
             )
             fold = self._engine.fold_round(
                 round_idx, results, schedule,
-                base_params=(
-                    self.params
-                    if (self.compression is not None or self.schema is not None)
-                    else None
-                ),
+                base_params=self.params if self.form.against_base else None,
             )
             self.fold_reports.append(fold)
             self.params = fold.params
@@ -1543,13 +1487,6 @@ class LiveRoundDriver:
                     group_dense = group_dense or {}
                     for k, v in o.group_dense.items():
                         group_dense[k] = max(group_dense.get(k, 0), int(v))
-            if self.schema is not None:
-                codec = ("structured" if self.compression is None
-                         else f"structured:{self.compression.codec}")
-            elif self.compression is not None:
-                codec = self.compression.codec
-            else:
-                codec = "none"
             log = RoundMessageLog(
                 s_msg_train_bytes=len(s_train_payload),
                 c_msg_train_bytes=c_train_bytes,
@@ -1557,7 +1494,7 @@ class LiveRoundDriver:
                 c_msg_test_bytes=max(
                     c_test_bytes, default=len(serialize_metrics(metrics))
                 ),
-                codec=codec,
+                codec=self.form.codec,
                 c_msg_train_dense_bytes=dense_train or None,
                 group_wire_bytes=group_wire,
                 group_dense_bytes=group_dense,
@@ -1856,20 +1793,10 @@ class LiveRoundDriver:
                         continue  # stale reply from a previous round
                     o = outcomes[cid]
                     try:
-                        # Compressed replies carry their codec in the
-                        # header; a frame corrupted in either encoding
-                        # raises the same DeserializationError, so the
-                        # §4.3 re-request recovery below is shared.
-                        if ev.header.get("structured"):
-                            from .compression import deserialize_structured
-
-                            params = deserialize_structured(ev.payload)
-                        elif ev.header.get("codec") is not None:
-                            from .compression import deserialize_update
-
-                            params = deserialize_update(ev.payload)
-                        else:
-                            params = deserialize_pytree(ev.payload, self.params)
+                        # A frame corrupted in any form raises the same
+                        # DeserializationError, so the §4.3 re-request
+                        # recovery below is shared.
+                        params = self.form.decode(ev.payload, self.params)
                     except DeserializationError:
                         # Corrupt frame: the reply arrived but is
                         # unusable — a §4.3 suspected fault.  The worker

@@ -724,13 +724,15 @@ def test_compressed_carry_is_materialized_dense_at_park():
     """Regression: a CompressedUpdate that missed its round's deadline
     was parked as-is, and the next round folded its quantized delta
     against the NEW base — silently shifting the straggler's update by
-    (new_base - origin_base).  The engine now dequantizes at park time,
-    so the carried value is base-independent."""
+    (new_base - origin_base).  The engine parks what its wire form says:
+    a compressed form's delta is dequantized at park time, so the
+    carried value is base-independent."""
     from repro.federated.agg_engine import plan_for
     from repro.federated.client import ClientResult
     from repro.federated.compression import (
         CompressedUpdate,
         CompressionSpec,
+        WireForm,
         compress,
     )
 
@@ -751,7 +753,7 @@ def test_compressed_carry_is_materialized_dense_at_park():
 
     schedule = DeterministicSchedule({"c0": 1.0, "c1": 9.0})
     engine = AsyncRoundEngine(deadline=FixedDeadline(t_round_s=2.0),
-                              carry_discount=0.5)
+                              carry_discount=0.5, form=WireForm("fp16"))
 
     # Round 1: c1's compressed update misses the deadline and is parked.
     r1_results = [

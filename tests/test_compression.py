@@ -4,6 +4,8 @@ updates, within codec tolerance, across ragged pytrees), error-feedback
 convergence, wire framing (truncation raises the typed error), builder
 validation, byte accounting, sim-vs-live parity with compression on, and
 the chaos corrupt_frame interaction on a compressed frame."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -704,3 +706,36 @@ def test_bad_base_round_tag_rejected():
     obj["br"] = "seven"
     with pytest.raises(DeserializationError, match="base round"):
         deserialize_update(msgpack.packb(obj, use_bin_type=True))
+
+
+def test_live_int8_replies_carry_their_round_and_a_stale_tag_is_refused():
+    """The live worker tags each int8 reply with the round whose global
+    weights it encoded against, so the round's aggregator checks the
+    base: every reply of a thread-transport round folds, and an update
+    tagged with another round is refused."""
+    from repro.federated.transport import MSG_C_TRAIN
+
+    driver = (Experiment().aggregation(compression="int8")
+              .transport(reply_timeout_s=30.0)
+              .serve(make_paced_clients({"c0": 0.0, "c1": 0.0}), init_params()))
+    tags = []
+    poll = driver.transport.poll
+
+    def tagged(timeout):
+        events = poll(timeout)
+        for ev in events:
+            if ev.kind == "message" and ev.header.get("kind") == MSG_C_TRAIN:
+                tags.append((ev.header["round_idx"], deserialize_update(ev.payload).base_round))
+        return events
+
+    driver.transport.poll = tagged
+    with driver:
+        driver.run(2)
+    assert sorted(tags) == [(1, 1), (1, 1), (2, 2), (2, 2)]
+    assert [len(rep.events) for rep in driver.fold_reports] == [2, 2]
+
+    update = compress(np.ones(3, np.float32), CompressionSpec("int8"), base_round=1)
+    agg = AggregationEngine().streaming(base=init_params(), base_round=2)
+    with pytest.raises(ValueError, match="base round 1"):
+        agg.add(update, 1.0)
+    agg.add(dataclasses.replace(update, base_round=2), 1.0)

@@ -32,3 +32,13 @@ def test_trace_matches_golden(name):
         f"trace for {name!r} diverged from the golden; see the structural "
         f"diff above (regenerate with scripts/golden_traces.py --update "
         f"if intended)")
+
+
+def test_diff_is_clean_on_itself_and_flags_a_truncation():
+    """The structural diff the goldens rest on: a trace matches itself,
+    and dropping its last event is a divergence."""
+    name = sorted(SCENARIOS)[0]
+    with open(golden_path(name)) as f:
+        trace = json.load(f)
+    assert diff_traces(trace, trace)
+    assert not diff_traces(trace, trace[:-1])

@@ -387,6 +387,52 @@ def test_structured_wire_roundtrip(codec):
     _assert_bit_identical(agg2.result(), agg1.result())
 
 
+def test_structured_int8_encode_runs_the_device_program_per_group():
+    """Each int8 group is one ClientCompressor over the group's leaves:
+    one device encode per group per round (counter ``encode_device``),
+    whose codes, scales and residual are the host reference's —
+    ``compress()`` of the group's delta, and that delta less its
+    ``decompress()`` to within one ulp."""
+    from repro import spans
+    from repro.federated.compression import (
+        QBLOCK,
+        CompressionSpec,
+        compress,
+        decompress,
+    )
+
+    shapes = ((3, 5000), (7,), (2, 2))
+    base = random_tree(np.random.default_rng(1), shapes)
+    local = random_tree(np.random.default_rng(2), shapes)
+    schema = UpdateSchema({"a": "leaf0", "b": ["leaf1", "leaf2"]})
+    resolved = schema.resolve(base)
+    enc = StructuredCompressor(schema, "int8")
+    for round_idx in (1, 2):
+        log = spans.SpanLog()
+        with spans.collect(log):
+            update = enc.encode(base, local, base_round=round_idx)
+        assert log.counters["encode_device"] == 2
+    # Round 2 re-encoded the same delta on top of round 1's residual;
+    # compare a fresh encoder's first round with the host reference.
+    enc = StructuredCompressor(schema, "int8")
+    update = enc.encode(base, local, base_round=1)
+    assert [n for n, _ in update.groups] == ["a", "b"]
+    for name, got in update.groups:
+        gp = resolved.group(name)
+        delta = (np.asarray(gp.flatten(local), np.float32)
+                 - np.asarray(gp.flatten(base), np.float32))
+        want = compress(delta, CompressionSpec("int8"), base_round=1)
+        assert got.base_round == 1 and got.total_elems == delta.size
+        np.testing.assert_array_equal(got.scales, want.scales)
+        np.testing.assert_array_equal(got.data, want.data)
+        residual = np.asarray(enc._groups[name]._residual)
+        assert residual.shape == (-(-delta.size // QBLOCK), QBLOCK)
+        # Within one ulp of the delta: the device may fuse ``x - q * s``
+        # into one multiply-add, which rounds once where numpy rounds twice.
+        off = np.abs(residual.reshape(-1)[: delta.size] - (delta - decompress(want)))
+        assert np.all(off <= np.spacing(np.abs(delta)))
+
+
 @pytest.mark.parametrize("codec", [None, "fp16"])
 def test_materialize_structured_pins_group_values(codec):
     """Parking form: a structured update materializes to base-independent
@@ -650,7 +696,7 @@ def test_masked_optimizer_moves_only_trainable_leaves():
 
 
 def test_zoo_config_with_lora_reaches_50x():
-    """The BENCH_structured acceptance shape: olmo-1b (reduced) with
+    """The federated-LoRA acceptance shape: olmo-1b (reduced) with
     rank-2 adapters on the attention projections ships >= 50x fewer
     c_msg_train elements than the dense model."""
     from repro.configs import get_config
@@ -669,3 +715,85 @@ def test_zoo_config_with_lora_reaches_50x():
     total = resolved.plan.total_elems
     adapters = resolved.group("adapters").total_elems
     assert total / adapters >= 50.0
+
+
+def test_zoo_lora_adapter_frame_is_50x_smaller_than_dense():
+    """The adapter-only ``c_msg_train`` frame of reduced olmo-1b with
+    rank-2 adapters, as a worker sends it, is >= 50x smaller than the
+    model's dense fp32 bytes."""
+    from repro.configs import get_config
+    from repro.federated.compression import WireForm
+    from repro.models.api import get_model
+    from repro.models.fl_models import LoRAConfig, inject_lora, lora_adapter_schema
+
+    cfg = get_config("olmo-1b").reduced().with_lora(2)
+    params = inject_lora(
+        get_model(cfg).init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1),
+        LoRAConfig(rank=cfg.lora_rank, alpha=cfg.lora_alpha,
+                   targets=cfg.lora_targets),
+    )
+    form = WireForm(schema=lora_adapter_schema())
+    fields, body = form.reply(form.encoder(), params, params, 1)
+    dense = plan_for(params).total_elems * 4
+    assert fields["dense_bytes"] == dense
+    assert dense / len(body) >= 50.0
+
+
+def test_sim_vs_live_lora_parity():
+    """Federated LoRA on both bus drivers: a masked optimizer trains only
+    the adapters, the workers ship the adapter group, and the two runs
+    agree on the effective weights, the trace and the byte accounting."""
+    from test_transport import ArraySilo, PacedClient, chain_replies, trace_signature
+    from repro.federated.transport import LiveRoundDriver, ThreadWorkerPool
+    from repro.models.fl_models import (
+        LoRAConfig,
+        inject_lora,
+        lora_adapter_schema,
+        lora_effective,
+    )
+    from repro.optim import make_optimizer, masked
+
+    lora = LoRAConfig(rank=1, alpha=1.0, targets=("w",))
+
+    def loss(params, batch):
+        x, y = batch
+        return jnp.mean(((x @ lora_effective(params, lora)["fc"]["w"])[:, 0] - y) ** 2)
+
+    def cohort():
+        rng = np.random.default_rng(7)
+        w_true = rng.standard_normal(3)
+        clients = []
+        for i in range(2):
+            x = rng.standard_normal((24, 3))
+            y = x @ w_true + 0.05 * rng.standard_normal(24)
+            clients.append(PacedClient(
+                f"c{i}",
+                ArraySilo(f"c{i}", jnp.asarray(x, jnp.float32), jnp.asarray(y, jnp.float32)),
+                loss, masked(make_optimizer("sgdm", 1e-2), ".lora_"), batch_size=8,
+            ))
+        return clients
+
+    init = inject_lora({"fc": {"w": jnp.zeros((3, 1), jnp.float32)}},
+                       jax.random.PRNGKey(0), lora)
+    schema = lora_adapter_schema()
+    server = AsyncFLServer(cohort(), init, schedule=DeterministicSchedule(0.0),
+                           schema=schema, measure_round_messages=True)
+    sim = server.run(4)
+    live_clients = cohort()
+    chain_replies(*live_clients)
+    driver = LiveRoundDriver(
+        ThreadWorkerPool(live_clients, init, schema=schema), init,
+        reply_timeout_s=60.0, schema=schema, measure_round_messages=True,
+    )
+    with driver:
+        live = driver.run(4)
+
+    np.testing.assert_allclose(
+        np.asarray(lora_effective(live.final_params, lora)["fc"]["w"]),
+        np.asarray(lora_effective(sim.final_params, lora)["fc"]["w"]),
+        rtol=0, atol=1e-5,
+    )
+    assert trace_signature(driver.trace) == trace_signature(server.bus.trace)
+    sim_log, live_log = sim.rounds[-1].message_log, driver.message_logs[-1]
+    assert live_log.group_wire_bytes == sim_log.group_wire_bytes
+    assert live_log.c_msg_train_bytes == sim_log.c_msg_train_bytes
